@@ -7,6 +7,7 @@ from repro.kernels.ops import (
     fused_lamb,
     fused_lamb_apply,
     fused_lamb_init,
+    kernel_calls,
     make_fused_lamb_step,
     pallas_spec_ok,
     resolve_flash_backend,
@@ -23,6 +24,7 @@ __all__ = [
     "fused_lamb",
     "fused_lamb_apply",
     "fused_lamb_init",
+    "kernel_calls",
     "lamb_update",
     "make_fused_lamb_step",
     "pallas_spec_ok",

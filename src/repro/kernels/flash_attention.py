@@ -36,7 +36,10 @@ CPU/GPU fallback, mirroring the ``fused_lamb`` backend scheme.
 
 Block sizes default to (128, 128) q×kv tiles — MXU-aligned (128 lanes) and
 small enough that q, k, v, acc tiles fit VMEM comfortably
-(4 · 128 · head_dim · 4B ≈ 256 KiB at head_dim=128).
+(4 · 128 · head_dim · 4B ≈ 256 KiB at head_dim=128).  The per-row
+residuals (``lse``, ``di``) travel as ``(B·H, 1, S)`` lane-dense rows and
+``kv_valid`` sits whole in SMEM, so every block meets the TPU's (8, 128)
+rule; ``tests/test_tpu_compile.py`` compiles the kernels for a v5e chip.
 """
 from __future__ import annotations
 
@@ -48,6 +51,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.sharding.context import batch_local
 
 NEG_INF = -1e30
 
@@ -115,20 +120,26 @@ def _maybe_when(run, body):
         pl.when(run)(body)
 
 
+def _valid(spec: FlashSpec, valid_ref, heads: int):
+    """This grid cell's kv_valid length, read from the (B,) SMEM array
+    (grid axis 0 enumerates batch × ``heads``)."""
+    return valid_ref[pl.program_id(0) // heads] if spec.use_valid else None
+
+
 # ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, valid_ref, o_ref, lse_ref,
+    valid_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     acc_ref, m_ref, l_ref,
-    *, spec: FlashSpec, offset: int,
+    *, spec: FlashSpec, offset: int, heads: int,
 ):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
     bq, bk = spec.block_q, spec.block_k
-    valid = valid_ref[0, 0] if spec.use_valid else None
+    valid = _valid(spec, valid_ref, heads)
 
     @pl.when(ki == 0)
     def init():
@@ -176,7 +187,8 @@ def _fwd_kernel(
     def finish():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = (m_ref[...] + jnp.log(l))[:, 0]
+        # the (bq, 1) column goes out lane-dense as one (1, bq) row
+        lse_ref[0] = (m_ref[...] + jnp.log(l)).reshape(1, bq)
 
 
 # ---------------------------------------------------------------------------
@@ -187,7 +199,8 @@ def _recompute_p_ds(spec, offset, valid, qi, ki, q, k, v, do, lse, di):
     """Block-local recompute shared by both backward kernels.
 
     Returns (p, ds) for one (bq, bk) tile: ``p = softmax(qkᵀ)`` rebuilt from
-    the logsumexp residual, ``ds = p * (do·vᵀ - di)``.
+    the logsumexp residual, ``ds = p * (do·vᵀ - di)``.  ``lse`` and ``di``
+    arrive as (1, bq) lane-dense rows and are used as (bq, 1) columns.
     """
     bq, bk = spec.block_q, spec.block_k
     s = jax.lax.dot_general(
@@ -198,7 +211,7 @@ def _recompute_p_ds(spec, offset, valid, qi, ki, q, k, v, do, lse, di):
     ok = _mask_conds(spec, rows, cols, offset, valid)
     if ok is not None:
         s = jnp.where(ok, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                  # (bq, bk), rows sum to 1
+    p = jnp.exp(s - lse.reshape(bq, 1))            # (bq, bk), rows sum to 1
     if ok is not None:
         # fully-masked rows have lse ≈ NEG_INF, where exp(s - lse) != 0:
         # zero them so dk/dv/dq see exactly the forward's p = 0
@@ -206,19 +219,19 @@ def _recompute_p_ds(spec, offset, valid, qi, ki, q, k, v, do, lse, di):
     dp = jax.lax.dot_general(                      # do · vᵀ
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
     )
-    ds = p * (dp - di[:, None])
+    ds = p * (dp - di.reshape(bq, 1))
     return p, ds
 
 
 def _dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, valid_ref, dq_ref,
+    valid_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
     acc_ref,
-    *, spec: FlashSpec, offset: int,
+    *, spec: FlashSpec, offset: int, heads: int,
 ):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
-    valid = valid_ref[0, 0] if spec.use_valid else None
+    valid = _valid(spec, valid_ref, heads)
 
     @pl.when(ki == 0)
     def init():
@@ -244,16 +257,16 @@ def _dq_kernel(
 
 
 def _dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, valid_ref,
+    valid_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
     dk_ref, dv_ref,
     dk_acc, dv_acc,
-    *, spec: FlashSpec, offset: int, nq: int,
+    *, spec: FlashSpec, offset: int, nq: int, heads: int,
 ):
     ki = pl.program_id(1)
     ti = pl.program_id(2)      # enumerates (head-in-group, q-block) pairs
     nt = pl.num_programs(2)
     qi = ti % nq
-    valid = valid_ref[0, 0] if spec.use_valid else None
+    valid = _valid(spec, valid_ref, heads)
 
     @pl.when(ti == 0)
     def init():
@@ -294,9 +307,15 @@ def _kv_imap(h: int, hkv: int):
     return lambda g, i, j: ((g // h) * hkv + (g % h) // group, j, 0)
 
 
-def _valid_spec(h_per_b: int):
-    imap = lambda g, i, j: (g // h_per_b, 0)
-    return pl.BlockSpec((1, 1), imap, memory_space=pltpu.SMEM)
+# per-example kv_valid lengths: the whole (B,) int32 array sits in SMEM and
+# each grid cell reads its own scalar (see ``_valid``)
+_VALID_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _row_spec(bq: int, imap):
+    """(1, 1, bq) block over a (G, 1, S) per-row array (lse, di): a
+    lane-dense row, which meets the TPU's (8, 128) block rule."""
+    return pl.BlockSpec((1, 1, bq), imap)
 
 
 def _pallas_fwd(spec: FlashSpec, q, k, v, valid):
@@ -307,25 +326,24 @@ def _pallas_fwd(spec: FlashSpec, q, k, v, valid):
     qf = q.reshape(b * h, s, d)
     kf = k.reshape(b * hkv, t, d)
     vf = v.reshape(b * hkv, t, d)
-    valid2 = valid.reshape(b, 1)
 
     grid = (b * h, s // bq, t // bk)
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, spec=spec, offset=t - s),
+        functools.partial(_fwd_kernel, spec=spec, offset=t - s, heads=h),
         grid=grid,
         in_specs=[
+            _VALID_SPEC,
             pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, 0)),
             pl.BlockSpec((1, bk, d), _kv_imap(h, hkv)),
             pl.BlockSpec((1, bk, d), _kv_imap(h, hkv)),
-            _valid_spec(h),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, 0)),
-            pl.BlockSpec((1, bq), lambda g, i, j: (g, i)),
+            _row_spec(bq, lambda g, i, j: (g, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, s), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),   # acc
@@ -333,7 +351,8 @@ def _pallas_fwd(spec: FlashSpec, q, k, v, valid):
             pltpu.VMEM((bq, 1), jnp.float32),   # denominator l
         ],
         interpret=interpret,
-    )(qf, kf, vf, valid2)
+        name="flash_fwd",
+    )(valid, qf, kf, vf)
     return o.reshape(b, h, s, d), lse.reshape(b, h, s)
 
 
@@ -353,47 +372,51 @@ def _pallas_bwd(spec: FlashSpec, q, k, v, valid, o, lse, do):
     kf = k.reshape(b * hkv, t, d)
     vf = v.reshape(b * hkv, t, d)
     dof = do.reshape(b * h, s, d)
-    lsef = lse.reshape(b * h, s)
-    dif = di.reshape(b * h, s)
-    valid2 = valid.reshape(b, 1)
+    lsef = lse.reshape(b * h, 1, s)
+    dif = di.reshape(b * h, 1, s)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, spec=spec, offset=offset),
+        functools.partial(_dq_kernel, spec=spec, offset=offset, heads=h),
         grid=(b * h, nq, nk),
         in_specs=[
+            _VALID_SPEC,
             pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, 0)),
             pl.BlockSpec((1, bk, d), _kv_imap(h, hkv)),
             pl.BlockSpec((1, bk, d), _kv_imap(h, hkv)),
             pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, 0)),
-            pl.BlockSpec((1, bq), lambda g, i, j: (g, i)),
-            pl.BlockSpec((1, bq), lambda g, i, j: (g, i)),
-            _valid_spec(h),
+            _row_spec(bq, lambda g, i, j: (g, 0, i)),
+            _row_spec(bq, lambda g, i, j: (g, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
-    )(qf, kf, vf, dof, lsef, dif, valid2)
+        name="flash_dq",
+    )(valid, qf, kf, vf, dof, lsef, dif)
 
     # dk/dv: one grid cell per kv tile; the innermost axis walks every
     # (q head of the GQA group × q block), summing into VMEM scratch
+    def q_head(n, ti):
+        return (n // hkv) * h + (n % hkv) * group + ti // nq
+
     def q_imap(n, jk, ti):
-        return ((n // hkv) * h + (n % hkv) * group + ti // nq, ti % nq, 0)
+        return (q_head(n, ti), ti % nq, 0)
 
     def qrow_imap(n, jk, ti):
-        return ((n // hkv) * h + (n % hkv) * group + ti // nq, ti % nq)
+        return (q_head(n, ti), 0, ti % nq)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, spec=spec, offset=offset, nq=nq),
+        functools.partial(_dkv_kernel, spec=spec, offset=offset, nq=nq,
+                          heads=hkv),
         grid=(b * hkv, nk, group * nq),
         in_specs=[
+            _VALID_SPEC,
             pl.BlockSpec((1, bq, d), q_imap),
             pl.BlockSpec((1, bk, d), lambda n, jk, ti: (n, jk, 0)),
             pl.BlockSpec((1, bk, d), lambda n, jk, ti: (n, jk, 0)),
             pl.BlockSpec((1, bq, d), q_imap),
-            pl.BlockSpec((1, bq), qrow_imap),
-            pl.BlockSpec((1, bq), qrow_imap),
-            _valid_spec(hkv),
+            _row_spec(bq, qrow_imap),
+            _row_spec(bq, qrow_imap),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda n, jk, ti: (n, jk, 0)),
@@ -408,7 +431,8 @@ def _pallas_bwd(spec: FlashSpec, q, k, v, valid, o, lse, do):
             pltpu.VMEM((bk, d), jnp.float32),   # dv accumulator
         ],
         interpret=interpret,
-    )(qf, kf, vf, dof, lsef, dif, valid2)
+        name="flash_dkv",
+    )(valid, qf, kf, vf, dof, lsef, dif)
 
     return (
         dq.reshape(b, h, s, d),
@@ -586,11 +610,6 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # public entry
 # ---------------------------------------------------------------------------
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("causal", "scale", "block_q", "block_k", "interpret",
-                     "window", "backend"),
-)
 def flash_attention(
     q: jnp.ndarray,  # (B, H, S, D)
     k: jnp.ndarray,  # (B, Hkv, T, D) — Hkv must divide H (GQA)
@@ -611,7 +630,29 @@ def flash_attention(
     ``flash_sdpa`` pads ragged lengths and masks the pad via ``kv_valid``.
     Keys at positions ``>= kv_valid[b]`` are masked out for every query row
     of example ``b`` (bidirectional padding / ragged-batch support).
+
+    Under a sharding context the Pallas backends run per data shard
+    (``sharding.context.batch_local``): attention is local to each example.
     """
+    if interpret and backend == "pallas":
+        backend = "interpret"
+    call = functools.partial(
+        _flash_attention, causal=causal, scale=scale, block_q=block_q,
+        block_k=block_k, window=window, backend=backend,
+    )
+    args = (q, k, v) if kv_valid is None else (q, k, v, kv_valid)
+    if backend == "xla":
+        return call(*args)
+    return batch_local(call, *args)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("causal", "scale", "block_q", "block_k", "window",
+                     "backend"),
+)
+def _flash_attention(q, k, v, kv_valid=None, *, causal, scale, block_q,
+                     block_k, window, backend):
     b, h, s, d = q.shape
     hkv, t = k.shape[1], k.shape[2]
     if h % max(hkv, 1):
@@ -619,8 +660,6 @@ def flash_attention(
     scale = scale if scale is not None else 1.0 / (d**0.5)
     block_q = min(block_q, s)
     block_k = min(block_k, t)
-    if interpret and backend == "pallas":
-        backend = "interpret"
     if backend not in ("pallas", "interpret", "xla"):
         raise ValueError(f"unknown flash backend {backend!r}")
     if backend != "xla" and (s % block_q or t % block_k):

@@ -38,8 +38,13 @@ resolved by :func:`resolve_ce_backend` exactly like
 ``resolve_flash_backend`` / ``resolve_fused_backend``.  Because the
 reductions in the XLA backend are plain jnp, GSPMD keeps the vocab-chunk
 log-sum-exp and both weight-gradient reductions *global* when ``w`` or
-``h`` are sharded over a mesh (the PR-4 ``pallas_spec_ok`` concern does
-not arise: on non-TPU meshes the resolver never picks the kernel path).
+``h`` are sharded over a mesh.  The kernels cannot be partitioned by
+GSPMD; on a mesh ``train/loss.py`` runs them per data shard under
+``shard_map`` (``sharding.context.batch_local``).
+
+Per-row vectors (labels, ``g``, and the ``nll`` / ``correct`` / ``lse``
+outputs) travel as ``(1, N)`` rows in ``(1, block_n)`` blocks: lane-dense,
+which meets the TPU's (8, 128) block rule where a 1-D block does not.
 """
 from __future__ import annotations
 
@@ -51,6 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.sharding.context import batch_local
 
 NEG_INF = -1e30
 _IDX_INF = np.iinfo(np.int32).max
@@ -119,8 +126,8 @@ def _fwd_kernel(
     l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
     m_ref[...] = m_new
 
-    lbl = lbl_ref[...]                            # (bn,) int32 in [0, vocab)
-    hit = cols == lbl[:, None]
+    lbl = lbl_ref[...].reshape(bn, 1)             # int32 in [0, vocab)
+    hit = cols == lbl
     ll_ref[...] = jnp.where(                      # label logit: set exactly once
         jnp.any(hit, axis=1, keepdims=True),
         jnp.sum(jnp.where(hit, s, 0.0), axis=1, keepdims=True),
@@ -136,10 +143,11 @@ def _fwd_kernel(
 
     @pl.when(j == nv - 1)
     def finish():
+        # (bn, 1) columns go out lane-dense as (1, bn) rows
         lse = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
-        lse_ref[...] = lse[:, 0]
-        nll_ref[...] = (lse - ll_ref[...])[:, 0]
-        corr_ref[...] = (bidx_ref[...][:, 0] == lbl).astype(jnp.float32)
+        lse_ref[...] = lse.reshape(1, bn)
+        nll_ref[...] = (lse - ll_ref[...]).reshape(1, bn)
+        corr_ref[...] = (bidx_ref[...] == lbl).astype(jnp.float32).reshape(1, bn)
 
 
 # ---------------------------------------------------------------------------
@@ -147,16 +155,20 @@ def _fwd_kernel(
 # ---------------------------------------------------------------------------
 
 def _chunk_dlogits(spec: CESpec, j, h, w, lbl, g, lse):
-    """(p - onehot(label)) · g for one (bn, bv) tile, rebuilt from ``lse``."""
+    """(p - onehot(label)) · g for one (bn, bv) tile, rebuilt from ``lse``.
+
+    ``lbl``, ``g`` and ``lse`` arrive as (1, bn) lane-dense rows and are
+    used as (bn, 1) columns.
+    """
     bn, bv = spec.block_n, spec.block_v
     s = jax.lax.dot_general(
         h, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
     )
     cols = j * bv + jax.lax.broadcasted_iota(jnp.int32, (bn, bv), 1)
     s = jnp.where(cols < spec.vocab, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                 # padded cols -> 0
-    onehot = (cols == lbl[:, None]).astype(jnp.float32)
-    return (p - onehot) * g[:, None]
+    p = jnp.exp(s - lse.reshape(bn, 1))           # padded cols -> 0
+    onehot = (cols == lbl.reshape(bn, 1)).astype(jnp.float32)
+    return (p - onehot) * g.reshape(bn, 1)
 
 
 def _dh_kernel(
@@ -213,17 +225,17 @@ def _pallas_fwd(spec: CESpec, h, w, lbl):
     vp = w.shape[0]
     bn, bv = spec.block_n, spec.block_v
     interpret = spec.backend == "interpret"
-    row = lambda i, j: (i,)
-    vec = jax.ShapeDtypeStruct((n,), jnp.float32)
+    row = pl.BlockSpec((1, bn), lambda i, j: (0, i))
+    vec = jax.ShapeDtypeStruct((1, n), jnp.float32)
     nll, corr, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, spec=spec),
         grid=(n // bn, vp // bv),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bv, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn,), row),
+            row,
         ],
-        out_specs=[pl.BlockSpec((bn,), row)] * 3,
+        out_specs=[row] * 3,
         out_shape=[vec, vec, vec],
         scratch_shapes=[
             pltpu.VMEM((bn, 1), jnp.float32),   # running max m
@@ -233,8 +245,9 @@ def _pallas_fwd(spec: CESpec, h, w, lbl):
             pltpu.VMEM((bn, 1), jnp.int32),     # best (argmax) index
         ],
         interpret=interpret,
-    )(h, w, lbl)
-    return nll, corr, lse
+        name="fused_ce_fwd",
+    )(h, w, lbl.reshape(1, n))
+    return nll[0], corr[0], lse[0]
 
 
 def _pallas_bwd(spec: CESpec, h, w, lbl, lse, g):
@@ -242,38 +255,39 @@ def _pallas_bwd(spec: CESpec, h, w, lbl, lse, g):
     vp = w.shape[0]
     bn, bv = spec.block_n, spec.block_v
     interpret = spec.backend == "interpret"
+    rows = (lbl.reshape(1, n), g.reshape(1, n), lse.reshape(1, n))
 
+    row = pl.BlockSpec((1, bn), lambda i, j: (0, i))
     dh = pl.pallas_call(
         functools.partial(_dh_kernel, spec=spec),
         grid=(n // bn, vp // bv),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bv, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
+            row, row, row,
         ],
         out_specs=pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), h.dtype),
         scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
         interpret=interpret,
-    )(h, w, lbl, g, lse)
+        name="fused_ce_dh",
+    )(h, w, *rows)
 
+    row_t = pl.BlockSpec((1, bn), lambda i, t: (0, t))
     dw = pl.pallas_call(
         functools.partial(_dw_kernel, spec=spec),
         grid=(vp // bv, n // bn),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, t: (t, 0)),
             pl.BlockSpec((bv, d), lambda i, t: (i, 0)),
-            pl.BlockSpec((bn,), lambda i, t: (t,)),
-            pl.BlockSpec((bn,), lambda i, t: (t,)),
-            pl.BlockSpec((bn,), lambda i, t: (t,)),
+            row_t, row_t, row_t,
         ],
         out_specs=pl.BlockSpec((bv, d), lambda i, t: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((vp, d), w.dtype),
         scratch_shapes=[pltpu.VMEM((bv, d), jnp.float32)],
         interpret=interpret,
-    )(h, w, lbl, g, lse)
+        name="fused_ce_dw",
+    )(h, w, *rows)
     return dh, dw
 
 
@@ -408,9 +422,6 @@ _fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
 # public entry
 # ---------------------------------------------------------------------------
 
-@functools.partial(
-    jax.jit, static_argnames=("backend", "block_n", "block_v", "interpret")
-)
 def fused_ce(
     h: jnp.ndarray,        # (N, D) gathered rows (any float dtype)
     w: jnp.ndarray,        # (V, D) vocab projection, embedding layout
@@ -433,11 +444,15 @@ def fused_ce(
     contributions then vanish exactly.  The weight is expected in the
     ``(V, D)`` embedding layout; transpose a ``(D, V)`` unembed matrix
     before calling.
+
+    Under a sharding context the Pallas backends run per data shard
+    (``sharding.context.batch_local``): the rows are split over the batch
+    axes, ``w`` is replicated into every shard, and its gradient is summed
+    over them.
     """
     n, d = h.shape
-    v, dw_ = w.shape
-    if dw_ != d:
-        raise ValueError(f"h feature dim {d} != w feature dim {dw_}")
+    if w.shape[1] != d:
+        raise ValueError(f"h feature dim {d} != w feature dim {w.shape[1]}")
     if labels.shape != (n,):
         raise ValueError(f"labels shape {labels.shape} != ({n},)")
     if interpret:
@@ -446,7 +461,17 @@ def fused_ce(
         mode = "interpret"
     else:
         mode = resolve_ce_backend(backend)
+    call = functools.partial(_fused_ce_rows, mode=mode, block_n=block_n,
+                             block_v=block_v)
+    if mode == "xla":
+        return call(h, labels, w)
+    return batch_local(call, h, labels, shared=(w,))
 
+
+@functools.partial(jax.jit, static_argnames=("mode", "block_n", "block_v"))
+def _fused_ce_rows(h, labels, w, *, mode, block_n, block_v):
+    n = h.shape[0]
+    v = w.shape[0]
     lbl = jnp.clip(labels.astype(jnp.int32), 0, v - 1)
     bv = min(block_v, v)
     pad_v = -v % bv

@@ -3,11 +3,13 @@
 The optimizer step is HBM-bandwidth bound: naively expressed in XLA it makes
 ~11 full passes over model-sized arrays (m/v EMA updates, bias correction,
 ratio, weight decay, two norm reductions, apply), and the global norm
-reductions split the fusion.  This kernel does it in two structured passes of
-VPU-aligned (1, BLOCK) tiles over the flattened (layers, P) view:
+reductions split the fusion.  This kernel does it in two structured passes
+over a ``(layers, R, 128)`` view of each leaf — every layer's P elements
+padded to ``R`` lane-dense rows of 128, walked in ``(1, tr, 128)`` tiles:
 
-  pass A (``_moments_kernel``): read g, x, m, v → write m', v' and per-block
-      partial sums of ‖x‖² and ‖u‖² (u = r + wd·x recomputed from m', v').
+  pass A (``_moments_kernel``): read g, x, m, v → write m', v' and
+      accumulate per-layer partial sums of ‖x‖² and ‖u‖² (u = r + wd·x
+      recomputed from m', v') into a resident ``(8, 128)`` block per layer.
   (host) per-layer trust ratio = phi(‖x‖)/‖u‖.
   pass B (``_apply_kernel``): read x, m', v' + ratio → write x' (u recomputed;
       cheaper than writing a param-sized u temp in pass A).
@@ -15,6 +17,7 @@ VPU-aligned (1, BLOCK) tiles over the flattened (layers, P) view:
 Total traffic ≈ 10 N  vs ≈ 21 N unfused.  The stacked-layers axis is grid
 dim 0, giving exact per-layer (scan-aware) trust ratios.  Padding tokens are
 zeros in all four arrays, making every derived quantity zero — no masks.
+The bias-correction scalars and the per-layer ratios are read from SMEM.
 """
 from __future__ import annotations
 
@@ -24,8 +27,20 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK = 8 * 1024  # lanes-aligned (8·128 | 8192 f32 = 32 KiB / operand)
+from repro.sharding.context import batch_local
+
+LANES = 128
+SUBLANES = 8
+DEFAULT_BLOCK = 64 * 1024  # elements per tile: (512, 128) f32 = 256 KiB / operand
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _fold(a: jnp.ndarray) -> jnp.ndarray:
+    """(tr, 128) → (8, 128) partial sums (vreg adds, no cross-lane reduce)."""
+    return jnp.sum(a.reshape(-1, SUBLANES, LANES), axis=0)
 
 
 def _moments_kernel(
@@ -33,38 +48,68 @@ def _moments_kernel(
     m_out, v_out, xsq_out, usq_out,
     *, b1: float, b2: float, eps: float, wd: float,
 ):
-    c1 = c_ref[0, 0]
-    c2 = c_ref[0, 1]
-    g = g_ref[...].astype(jnp.float32)
-    x = x_ref[...].astype(jnp.float32)
-    m_new = b1 * m_ref[...] + (1.0 - b1) * g
-    v_new = b2 * v_ref[...] + (1.0 - b2) * g * g
-    m_out[...] = m_new
-    v_out[...] = v_new
+    c1 = c_ref[0]
+    c2 = c_ref[1]
+    g = g_ref[0].astype(jnp.float32)
+    x = x_ref[0].astype(jnp.float32)
+    m_new = b1 * m_ref[0] + (1.0 - b1) * g
+    v_new = b2 * v_ref[0] + (1.0 - b2) * g * g
+    m_out[0] = m_new
+    v_out[0] = v_new
     r = (m_new * c1) / (jnp.sqrt(v_new * c2) + eps)
     u = r + wd * x
-    xsq_out[0, 0] = jnp.sum(x * x)
-    usq_out[0, 0] = jnp.sum(u * u)
+
+    # the (8, 128) sum blocks stay resident across a layer's tiles
+    @pl.when(pl.program_id(1) == 0)
+    def init():
+        xsq_out[...] = jnp.zeros_like(xsq_out)
+        usq_out[...] = jnp.zeros_like(usq_out)
+
+    xsq_out[0] += _fold(x * x)
+    usq_out[0] += _fold(u * u)
 
 
 def _apply_kernel(
     c_ref, ratio_ref, x_ref, m_ref, v_ref, x_out,
     *, eps: float, wd: float, lr: float,
 ):
-    c1 = c_ref[0, 0]
-    c2 = c_ref[0, 1]
-    x = x_ref[...].astype(jnp.float32)
-    r = (m_ref[...] * c1) / (jnp.sqrt(v_ref[...] * c2) + eps)
+    c1 = c_ref[0]
+    c2 = c_ref[1]
+    x = x_ref[0].astype(jnp.float32)
+    r = (m_ref[0] * c1) / (jnp.sqrt(v_ref[0] * c2) + eps)
     u = r + wd * x
-    x_out[...] = (x - lr * ratio_ref[0, 0] * u).astype(x_out.dtype)
+    ratio = ratio_ref[pl.program_id(0)]
+    x_out[0] = (x - lr * ratio * u).astype(x_out.dtype)
 
 
-def _pad_flat(a: jnp.ndarray, layers: int, p_pad: int) -> jnp.ndarray:
+def _pad_rows(a: jnp.ndarray, layers: int, p_pad: int) -> jnp.ndarray:
+    """(layers, ...) → zero-padded (layers, p_pad // 128, 128)."""
     flat = a.reshape(layers, -1)
     pad = p_pad - flat.shape[1]
     if pad:
         flat = jnp.pad(flat, ((0, 0), (0, pad)))
-    return flat
+    return flat.reshape(layers, p_pad // LANES, LANES)
+
+
+def lamb_update(x, g, m, v, step, lr_t=None, **options):
+    """Fused LAMB step on one tensor.  Returns (x', m', v').
+
+    ``step`` is the 1-based iteration (traced scalar); ``lr_t`` an optional
+    traced LR (schedules) that multiplies ``lr``.  ``options`` are the
+    static settings of :func:`_lamb_update`: betas, eps, weight decay,
+    ``lr``, trust-ratio bounds, ``layer_axis`` (0 or None: stacks put
+    layers first by convention), ``block``, ``interpret``.
+    ``return_ratio=True`` appends the applied per-layer trust ratio — the
+    exact phi(‖x‖)/‖u‖ the kernel scaled by, *before* the lr fold-in — as a
+    fourth output (shape ``(layers,)``; the telemetry recorder's aux).
+
+    The kernel works on the whole leaf, so it takes replicated leaves only
+    (``ops.pallas_spec_ok``); under a sharding context every shard runs the
+    same update (``sharding.context.batch_local``).
+    """
+    shared = (x, g, m, v, step) + (() if lr_t is None else (lr_t,))
+    return batch_local(functools.partial(_lamb_update, **options),
+                       shared=shared)
 
 
 @functools.partial(
@@ -74,7 +119,7 @@ def _pad_flat(a: jnp.ndarray, layers: int, p_pad: int) -> jnp.ndarray:
         "layer_axis", "block", "interpret", "apply_trust", "return_ratio",
     ),
 )
-def lamb_update(
+def _lamb_update(
     x: jnp.ndarray,
     g: jnp.ndarray,
     m: jnp.ndarray,
@@ -95,54 +140,49 @@ def lamb_update(
     interpret: bool = False,
     return_ratio: bool = False,
 ) -> Tuple[jnp.ndarray, ...]:
-    """Fused LAMB step on one tensor.  Returns (x', m', v').
-
-    ``step`` is the 1-based iteration (traced scalar); betas/lr are static.
-    ``layer_axis`` must be 0 or None (stacks put layers first by convention).
-    ``return_ratio=True`` appends the applied per-layer trust ratio — the
-    exact phi(‖x‖)/‖u‖ the kernel scaled by, *before* the lr fold-in — as a
-    fourth output (shape ``(layers,)``; the telemetry recorder's aux).
-    """
     if layer_axis not in (None, -1, 0):
         raise ValueError("lamb_update supports layer_axis in {None, 0}")
     stacked = layer_axis == 0
     layers = x.shape[0] if stacked else 1
     per_layer = x.size // layers
-    blk = min(block, max(pl.next_power_of_2(per_layer), 128))
-    p_pad = pl.cdiv(per_layer, blk) * blk
-    nb = p_pad // blk
+    rows = pl.cdiv(per_layer, LANES)
+    tr = min(max(block // LANES, SUBLANES), pl.cdiv(rows, SUBLANES) * SUBLANES)
+    nb = pl.cdiv(rows, tr)
+    p_pad = nb * tr * LANES
 
     orig_shape, orig_dtype = x.shape, x.dtype
-    xf = _pad_flat(x, layers, p_pad)
-    gf = _pad_flat(g, layers, p_pad)
-    mf = _pad_flat(m.astype(jnp.float32), layers, p_pad)
-    vf = _pad_flat(v.astype(jnp.float32), layers, p_pad)
+    xf = _pad_rows(x, layers, p_pad)
+    gf = _pad_rows(g, layers, p_pad)
+    mf = _pad_rows(m.astype(jnp.float32), layers, p_pad)
+    vf = _pad_rows(v.astype(jnp.float32), layers, p_pad)
+    rows_shape = (layers, nb * tr, LANES)
 
     t = step.astype(jnp.float32)
-    c = jnp.stack([1.0 / (1.0 - b1**t), 1.0 / (1.0 - b2**t)]).reshape(1, 2)
+    c = jnp.stack([1.0 / (1.0 - b1**t), 1.0 / (1.0 - b2**t)])
 
-    tile = pl.BlockSpec((1, blk), lambda l, i: (l, i))
-    cell = pl.BlockSpec((1, 1), lambda l, i: (l, i))
-    scal = pl.BlockSpec((1, 2), lambda l, i: (0, 0))
+    tile = pl.BlockSpec((1, tr, LANES), lambda l, i: (l, i, 0))
+    sums = pl.BlockSpec((1, SUBLANES, LANES), lambda l, i: (l, 0, 0))
+    sums_shape = jax.ShapeDtypeStruct((layers, SUBLANES, LANES), jnp.float32)
 
     m_new, v_new, xsq, usq = pl.pallas_call(
         functools.partial(
             _moments_kernel, b1=b1, b2=b2, eps=eps, wd=weight_decay
         ),
         grid=(layers, nb),
-        in_specs=[scal, tile, tile, tile, tile],
-        out_specs=[tile, tile, cell, cell],
+        in_specs=[_SMEM, tile, tile, tile, tile],
+        out_specs=[tile, tile, sums, sums],
         out_shape=[
-            jax.ShapeDtypeStruct((layers, p_pad), jnp.float32),
-            jax.ShapeDtypeStruct((layers, p_pad), jnp.float32),
-            jax.ShapeDtypeStruct((layers, nb), jnp.float32),
-            jax.ShapeDtypeStruct((layers, nb), jnp.float32),
+            jax.ShapeDtypeStruct(rows_shape, jnp.float32),
+            jax.ShapeDtypeStruct(rows_shape, jnp.float32),
+            sums_shape,
+            sums_shape,
         ],
         interpret=interpret,
+        name="lamb_moments",
     )(c, xf, gf, mf, vf)
 
-    w_norm = jnp.sqrt(jnp.sum(xsq, axis=1))
-    u_norm = jnp.sqrt(jnp.sum(usq, axis=1))
+    w_norm = jnp.sqrt(jnp.sum(xsq, axis=(1, 2)))
+    u_norm = jnp.sqrt(jnp.sum(usq, axis=(1, 2)))
     if phi_lo is not None or phi_hi is not None:
         w_norm = jnp.clip(
             w_norm,
@@ -155,20 +195,20 @@ def lamb_update(
     trust = ratio  # pre-lr applied ratio (telemetry aux)
     if lr_t is not None:
         ratio = ratio * lr_t.astype(jnp.float32)
-    ratio = ratio.reshape(layers, 1)
 
-    rcell = pl.BlockSpec((1, 1), lambda l, i: (l, 0))
     x_new = pl.pallas_call(
         functools.partial(_apply_kernel, eps=eps, wd=weight_decay, lr=lr),
         grid=(layers, nb),
-        in_specs=[scal, rcell, tile, tile, tile],
+        in_specs=[_SMEM, _SMEM, tile, tile, tile],
         out_specs=tile,
-        out_shape=jax.ShapeDtypeStruct((layers, p_pad), orig_dtype),
+        out_shape=jax.ShapeDtypeStruct(rows_shape, orig_dtype),
         interpret=interpret,
+        name="lamb_apply",
     )(c, ratio, xf, m_new, v_new)
 
     def unflat(a, dtype):
-        return a[:, :per_layer].reshape(orig_shape).astype(dtype)
+        flat = a.reshape(layers, p_pad)[:, :per_layer]
+        return flat.reshape(orig_shape).astype(dtype)
 
     out = (
         unflat(x_new, orig_dtype),

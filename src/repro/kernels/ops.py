@@ -23,7 +23,8 @@ the chunked-XLA scan elsewhere (same backend scheme as ``fused_lamb``).
 """
 from __future__ import annotations
 
-from typing import Any, NamedTuple, Optional, Tuple
+import re
+from typing import Any, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,11 +41,29 @@ from repro.optim.base import (
 )
 
 
+def kernel_calls(hlo: str, name: str) -> List[str]:
+    """Result types of the ``tpu_custom_call``s of the Pallas kernel
+    ``name`` in compiled TPU HLO text (``compiled.as_text()``).
+
+    Every ``pallas_call`` in this package carries a stable ``name``
+    (``flash_fwd``, ``fused_ce_dw``, ``lamb_apply``, ...), which shows up
+    in the custom call's op name; an empty list means the kernel is not in
+    the program.
+    """
+    calls = []
+    for line in hlo.splitlines():
+        if ('custom_call_target="tpu_custom_call"' in line
+                and f"/{name}/pallas_call" in line):
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) custom-call\(", line)
+            calls.append(m.group(1) if m else line)
+    return calls
+
+
 def pallas_spec_ok(spec) -> bool:
     """True if a parameter with this PartitionSpec can feed the Pallas kernel.
 
-    The fused kernel flattens each leaf to a padded ``(layers, P)`` view and
-    grids over it on one device — valid only for replicated leaves.  A leaf
+    The fused kernel works on a padded ``(layers, R, 128)`` view of the
+    whole leaf — valid only for replicated leaves.  A leaf
     sharded on any mesh axis (FSDP ``embed``, TP ``heads``/``ff``) must take
     the fused-XLA ``lamb_update_ref`` path instead, where GSPMD inserts the
     collectives that keep the per-layer ‖x‖/‖u‖ trust-ratio reductions

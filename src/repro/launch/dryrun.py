@@ -122,14 +122,6 @@ def build_encoder_forward(model, shape: InputShape, mesh, rules):
 # main runner
 # ---------------------------------------------------------------------------
 
-def _cost_dict(cost) -> Dict[str, float]:
-    """cost_analysis() across JAX API generations: jax 0.4.x returns a
-    one-element list of dicts, newer releases a plain dict."""
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost) if cost else {}
-
-
 def _mem_dict(mem) -> Dict[str, float]:
     out = {}
     for attr in (
@@ -236,7 +228,7 @@ def run_dryrun(
         compiled = lowered.compile()
         t_compile = time.perf_counter() - t0 - t_lower
 
-    cost = _cost_dict(compiled.cost_analysis())
+    cost = dict(compiled.cost_analysis() or {})
     try:
         mem = _mem_dict(compiled.memory_analysis())
     except Exception as e:  # pragma: no cover
@@ -270,7 +262,7 @@ def run_dryrun(
                 compiled_u = jax.jit(
                     fn_u, in_shardings=sh_u, donate_argnums=dn_u
                 ).lower(*args_u).compile()
-            cost = _cost_dict(compiled_u.cost_analysis()) or cost
+            cost = dict(compiled_u.cost_analysis() or {}) or cost
             hlo = compiled_u.as_text()
             cost_source = "unrolled"
         except Exception as e:  # pragma: no cover — fall back to scanned cost

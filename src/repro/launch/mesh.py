@@ -16,31 +16,22 @@ import numpy as np
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across API generations: newer releases expose
-    jax.sharding.AxisType and expect explicit axis_types; jax 0.4.x has
-    neither (all axes are implicitly auto)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto (GSPMD-partitioned)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def abstract_mesh(shape: Sequence[int], names: Sequence[str]):
-    """AbstractMesh across JAX API generations (no devices needed).
+    """An AbstractMesh (no devices needed).
 
-    Newer releases take ``(axis_sizes, axis_names)``; jax 0.4.x takes one
-    ``((name, size), ...)`` tuple.  Abstract meshes carry only axis
-    structure — enough for ``resolve_spec``/``specs_for`` — so sharding
-    layouts can be planned on machines without the target device count.
+    Abstract meshes carry only axis structure — enough for
+    ``resolve_spec``/``specs_for`` — so sharding layouts can be planned on
+    machines without the target device count.
     """
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(shape), tuple(names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, shape)))
+    return AbstractMesh(tuple(shape), tuple(names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

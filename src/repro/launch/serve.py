@@ -32,6 +32,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, smoke_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.telemetry import EventLog, RunReport, run_provenance
 from repro.train.preempt import PreemptionHandler
@@ -91,6 +92,7 @@ def main() -> None:
                     help="write events.jsonl + RUN_REPORT.json here "
                          "(continuous mode; off = null sink)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.is_encoder:

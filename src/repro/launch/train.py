@@ -73,6 +73,7 @@ import jax
 
 from repro import core
 from repro.configs import get_config, smoke_config
+from repro.launch.cache import enable_compile_cache
 from repro.configs.base import TrainConfig
 from repro.core.mixed_batch import make_stage
 from repro.data import DataPipeline
@@ -166,6 +167,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.accum_steps < 1:
         raise SystemExit(f"--accum-steps must be >= 1, got {args.accum_steps}")

@@ -10,10 +10,10 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Mapping, Optional, Sequence
+from typing import Callable, Mapping, Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.sharding.axes import default_act_rules, resolve_spec
 
@@ -82,3 +82,35 @@ def shard_act(x, axes: Sequence[Optional[str]]):
         raise ValueError(f"rank mismatch: {x.shape} vs logical axes {axes}")
     spec = resolve_spec(x.shape, axes, ctx.act_rules, ctx.mesh)
     return jax.lax.with_sharding_constraint(x, NamedSharding(ctx.mesh, spec))
+
+
+def batch_local(fn: Callable, *batched, shared: Sequence = ()):
+    """Call ``fn(*batched, *shared)`` shard by shard on the ambient mesh.
+
+    GSPMD cannot partition a Pallas TPU kernel (a ``tpu_custom_call``), so
+    a kernel call inside a multi-device step goes through ``jax.shard_map``
+    with every mesh axis manual.  ``batched`` arrays are split on their
+    leading (batch) dim over the context's ``batch`` axes and ``shared``
+    arrays are replicated into every shard.  ``fn`` must be row-local, so
+    no collective is needed inside; its outputs come back split like the
+    batch (replicated when nothing is batched).  The transpose of
+    ``shard_map`` sums a shared input's cotangent over the shards, so
+    gradients stay global.  With no context, or a one-device mesh, this
+    is a plain call.  The Pallas entry points (``flash_attention``,
+    ``fused_ce``, ``lamb_update``) route themselves through it, so their
+    callers need not know the rule.
+    """
+    ctx = current()
+    if ctx is None or ctx.mesh.size == 1:
+        return fn(*batched, *shared)
+    row = PartitionSpec()
+    if batched:
+        lead = batched[0]
+        axes = ("batch",) + (None,) * (lead.ndim - 1)
+        spec = resolve_spec(lead.shape, axes, ctx.act_rules, ctx.mesh)
+        row = PartitionSpec(*spec[:1])
+    return jax.shard_map(
+        fn, mesh=ctx.mesh,
+        in_specs=(row,) * len(batched) + (PartitionSpec(),) * len(shared),
+        out_specs=row, check_vma=False,
+    )(*batched, *shared)

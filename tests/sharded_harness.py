@@ -16,6 +16,9 @@ Prints one JSON object on the last stdout line.  Scenarios:
   mlm_flash     the paper path: bert-smoke MLM through flash attention,
                 fused LAMB and the fused-CE head (plus the dense-head
                 variant), sharded ≡ single-device
+  mlm_kernels   the same path with flash, the fused CE head and fused LAMB
+                on their Pallas kernels (interpreted): on a mesh they run
+                under shard_map, sharded ≡ single-device
   stages        mixed-batch fit_stages re-jits correctly on a mesh
   checkpoint    FSDP state saved on data=8 restores onto data=4,model=2
                 (values, placements, and a post-restore step)
@@ -79,7 +82,11 @@ from repro.core import make_stage  # noqa: E402
 from repro.data import DataPipeline  # noqa: E402
 from repro.launch.mesh import make_mesh_from_spec  # noqa: E402
 from repro.models import build_model  # noqa: E402
-from repro.sharding import shardings_for, train_state_shardings  # noqa: E402
+from repro.sharding import (  # noqa: E402
+    shardings_for,
+    train_state_shardings,
+    use_sharding,
+)
 from repro.telemetry import EventLog  # noqa: E402
 from repro.train import (  # noqa: E402
     FaultInjector,
@@ -181,6 +188,30 @@ def scenario_mlm_flash():
         "fused_ce": _equiv_entry(cfg, tc),
         "dense_head": _equiv_entry(cfg.replace(use_fused_ce_head=False), tc),
     }
+
+
+def scenario_mlm_kernels():
+    """The paper path with every kernel family on its Pallas path (run by
+    the interpreter): on a mesh the kernel calls go through shard_map, so
+    GSPMD never has to partition them.  Sharded must still match
+    single-device, and the sharded step must hold the manual regions."""
+    from repro.kernels import ops
+
+    cfg = smoke_config("bert-large").replace(fused_ce_backend="interpret")
+    tc = TrainConfig(optimizer="lamb", learning_rate=1e-3, use_fused_lamb=True,
+                     fused_backend="interpret")
+    resolve = ops.resolve_flash_backend
+    ops.resolve_flash_backend = lambda backend="auto": "interpret"
+    try:
+        out = _equiv_entry(cfg, tc)
+        tr = _fit(cfg, tc, MESHES[0], steps=1)
+        batch = next(DataPipeline(cfg, BATCH, SEQ, seed=0, mesh=tr.mesh))
+        with use_sharding(tr.shard_ctx):
+            text = tr._step_fn.lower(tr.state, batch).as_text()
+        out["manual_regions"] = text.count("sdy.manual_computation")
+    finally:
+        ops.resolve_flash_backend = resolve
+    return out
 
 
 def scenario_stages():
@@ -624,6 +655,7 @@ SCENARIOS = {
     "equiv": scenario_equiv,
     "lans": scenario_lans,
     "mlm_flash": scenario_mlm_flash,
+    "mlm_kernels": scenario_mlm_kernels,
     "stages": scenario_stages,
     "checkpoint": scenario_checkpoint,
     "crash_resume": scenario_crash_resume,

@@ -23,6 +23,10 @@ LAMB_SHAPES = [
     ((2, 64, 32), 0),
     ((1, 9000), 0),
     ((3, 4096), 0),
+    # several default-size tiles per layer with a padded last tile: the
+    # per-layer norm sums accumulate across tiles
+    ((2, 140000), 0),
+    ((140000,), None),
 ]
 
 

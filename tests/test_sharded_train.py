@@ -70,6 +70,17 @@ def test_mlm_flash_fused_sharded_matches(report, head, mesh):
     assert entry["loss_diff"] < LOSS_TOL, entry
 
 
+@pytest.mark.parametrize("mesh", ["data=8,model=1", "data=4,model=2"])
+def test_mlm_kernels_under_shard_map_match(report, mesh):
+    """The same path on the Pallas kernels (interpreted): GSPMD cannot
+    partition a TPU kernel, so on a mesh the kernel calls run per shard
+    under shard_map — and the result must not change."""
+    entry = report["mlm_kernels"][mesh]
+    assert entry["param_maxdiff"] < PARAM_TOL, entry
+    assert entry["loss_diff"] < LOSS_TOL, entry
+    assert report["mlm_kernels"]["manual_regions"] > 0
+
+
 def test_mixed_batch_stages_run_sharded(report):
     assert report["stages"]["final_step"] == 4
     assert report["stages"]["finite"]
