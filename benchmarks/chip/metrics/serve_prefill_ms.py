@@ -1,0 +1,10 @@
+"""Median device time of the prefill program (the engine's jitted
+``prefill``) in the traced part of the window."""
+import numpy as np
+
+from chipbench import trace
+
+
+def read(ctx):
+    events = trace.module_events(ctx.trace, r"^jit_prefill")
+    return float(np.median([e[2] for e in events])) / 1e6 if events else None
