@@ -9,6 +9,9 @@ Placement: pass either an explicit ``sharding`` (applied to every leaf) or a
 ``mesh`` — with a mesh, batches are split over its data axes
 (``sharding.batch_sharding``), which is exactly the layout the sharded train
 step declares via ``in_shardings``, so the jit boundary never reshards.
+
+Each prefetched batch shows in a profiler trace as ``data.next`` (the
+source) and ``data.place`` (its ``device_put``).
 """
 from __future__ import annotations
 
@@ -22,6 +25,7 @@ from repro.configs.base import ModelConfig
 from repro.data.synthetic import batch_iterator
 from repro.sharding.axes import batch_axes, dp_size
 from repro.sharding.placement import batch_sharding
+from repro.telemetry.spans import trace_span
 
 
 class DataPipeline:
@@ -58,11 +62,13 @@ class DataPipeline:
 
     def _fill(self):
         while len(self._buf) < self.prefetch:
-            b = next(self._it)
+            with trace_span("data.next"):
+                b = next(self._it)
             if self.sharding is not None:
-                b = jax.tree.map(
-                    lambda x, s=self.sharding: jax.device_put(x, s), b
-                )
+                with trace_span("data.place"):
+                    b = jax.tree.map(
+                        lambda x, s=self.sharding: jax.device_put(x, s), b
+                    )
             self._buf.append(b)
 
     def __iter__(self) -> Iterator[Dict[str, np.ndarray]]:

@@ -41,6 +41,16 @@ Every submitted request ends in exactly one terminal
 :class:`~repro.serve.scheduler.RequestStatus`; ``generate`` asserts the
 four terminal counts are disjoint and sum to the submitted total.
 
+Profiler spans (``telemetry.trace_span``; free with no profiler running):
+each loop iteration is a ``serve.turn`` (args ``admitted``, ``active``,
+``compiles``) holding ``serve.schedule`` (timeouts, quarantine release,
+``scheduler.admit``), one ``serve.admit`` per admission (arg ``rid``; its
+``serve.prefill`` dispatch, ``serve.sample`` and ``serve.insert``) and the
+``serve.decode`` step (``serve.device_state``, ``serve.dispatch``,
+``serve.wait`` on the sampled tokens, ``serve.emit``).  Inside the jitted
+decode step the named scopes ``decode.model``, ``decode.sample`` and
+``kv_pool.reset`` label the device ops.
+
 Determinism caveat: greedy outputs match the static ``Engine`` token-for-token
 on every row-independent family (dense/GQA/SWA, MLA, mamba/hybrid, xLSTM).
 Capacity-factor MoE couples rows — per-expert capacity and drop order depend
@@ -68,7 +78,7 @@ from repro.serve.scheduler import (
     ServeRequest,
 )
 from repro.sharding.context import ShardCtx, use_sharding
-from repro.telemetry import EventLog
+from repro.telemetry import EventLog, compile_count, trace_span
 
 TokenCallback = Callable[[ServeRequest, int], None]
 
@@ -104,17 +114,20 @@ def make_pool_decode_step(model: Model, *, greedy: bool = False):
 
     def step(params, cache, tokens, positions, active, temps, top_k,
              base_rng, step_no):
-        logits, cache = model.decode(
-            params, {"tokens": tokens[:, None]}, cache, positions[:, None]
-        )
-        last = logits[:, -1]
-        if greedy:
-            nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        else:
-            nxt = sample_tokens(jax.random.fold_in(base_rng, step_no), last,
-                                temps, top_k)
-        nxt = jnp.where(active, nxt, 0)
-        cache = reset_inactive(cache, active)
+        with jax.named_scope("decode.model"):
+            logits, cache = model.decode(
+                params, {"tokens": tokens[:, None]}, cache, positions[:, None]
+            )
+        with jax.named_scope("decode.sample"):
+            last = logits[:, -1]
+            if greedy:
+                nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
+            else:
+                nxt = sample_tokens(jax.random.fold_in(base_rng, step_no),
+                                    last, temps, top_k)
+            nxt = jnp.where(active, nxt, 0)
+        with jax.named_scope("kv_pool.reset"):
+            cache = reset_inactive(cache, active)
         new_pos = jnp.where(active, positions + 1, 0)
         return nxt, new_pos, cache
 
@@ -318,45 +331,51 @@ class ContinuousEngine:
         self, req: ServeRequest, clock: Callable[[], float],
         on_token: Optional[TokenCallback],
     ) -> None:
-        req.attempts += 1
-        if self._degraded:
-            # degraded mode: cap the generation budget of new admissions so
-            # a stalling backend sheds decode work before it sheds requests
-            req.max_new_tokens = max(
-                1, min(req.max_new_tokens, self.degrade_max_new_tokens))
-        slot = self.pool.acquire()
-        assert slot is not None, "admit() respects free-slot budget"
-        prompt = np.asarray(req.prompt, np.int32)
-        last, cache1 = self._prefill(self.params, jnp.asarray(prompt[None]))
-        tok = int(
-            sample_tokens(
-                self._next_key(), last,
-                jnp.full((1,), req.temperature, jnp.float32),
-                jnp.full((1,), req.top_k, jnp.int32),
-            )[0]
-        )
-        self.pool.insert(cache1, slot, len(prompt))
-        self._dev = None  # slot churn: device per-slot state is stale
-        # fault-injection point: the first sample of this attempt.  A real
-        # detector would check np.isnan(logits) / cache health here.
-        kind = (self.faults.fire_request(req.rid)
-                if self.faults is not None else None)
-        if kind is not None:
-            self._transient_failure(req, slot, kind, clock())
-            return
-        req.out_tokens.append(tok)
-        # the int() above blocked on the prefill: stamp after, not before
-        req.first_token_s = clock()
-        if on_token is not None:
-            on_token(req, tok)
-        if self._finished(req, tok):
+        with trace_span("serve.admit", rid=req.rid):
+            req.attempts += 1
+            if self._degraded:
+                # degraded mode: cap the generation budget of new
+                # admissions so a stalling backend sheds decode work
+                # before it sheds requests
+                req.max_new_tokens = max(
+                    1, min(req.max_new_tokens, self.degrade_max_new_tokens))
+            slot = self.pool.acquire()
+            assert slot is not None, "admit() respects free-slot budget"
+            prompt = np.asarray(req.prompt, np.int32)
+            with trace_span("serve.prefill"):
+                last, cache1 = self._prefill(self.params,
+                                             jnp.asarray(prompt[None]))
+            with trace_span("serve.sample"):
+                tok = int(
+                    sample_tokens(
+                        self._next_key(), last,
+                        jnp.full((1,), req.temperature, jnp.float32),
+                        jnp.full((1,), req.top_k, jnp.int32),
+                    )[0]
+                )
+            with trace_span("serve.insert"):
+                self.pool.insert(cache1, slot, len(prompt))
+            self._dev = None  # slot churn: device per-slot state is stale
+            # fault-injection point: the first sample of this attempt.  A
+            # real detector would check np.isnan(logits) / cache health here.
+            kind = (self.faults.fire_request(req.rid)
+                    if self.faults is not None else None)
+            if kind is not None:
+                self._transient_failure(req, slot, kind, clock())
+                return
+            req.out_tokens.append(tok)
+            # the int() above blocked on the prefill: stamp after, not before
+            req.first_token_s = clock()
+            if on_token is not None:
+                on_token(req, tok)
+            if self._finished(req, tok):
+                self._slot_req[slot] = req
+                self._finish(slot, req.first_token_s)
+                return
             self._slot_req[slot] = req
-            self._finish(slot, req.first_token_s)
-            return
-        self._slot_req[slot] = req
-        self._tokens[slot] = tok
-        self._temps[slot] = req.temperature
-        self._top_k[slot] = req.top_k
+            self._tokens[slot] = tok
+            self._temps[slot] = req.temperature
+            self._top_k[slot] = req.top_k
 
     def _release_quarantined(self, *, force: bool = False) -> None:
         for slot, due in list(self._quarantined.items()):
@@ -389,32 +408,38 @@ class ContinuousEngine:
     def _step(
         self, clock: Callable[[], float], on_token: Optional[TokenCallback]
     ) -> None:
-        active = self.pool.active_mask.copy()
-        tokens_d, pos_d, active_d, temps_d, topk_d = self._device_state()
-        decode = (
-            self._decode_greedy
-            if float(self._temps[active].max(initial=0.0)) <= 0.0
-            else self._decode_sample
-        )
-        toks_d, pos_d, self.pool.cache = decode(
-            self.params, self.pool.cache, tokens_d, pos_d, active_d,
-            temps_d, topk_d, self.rng, np.int32(self._step_no),
-        )
-        self._step_no += 1
-        toks = np.asarray(toks_d)  # the loop's one device→host sync
-        now = clock()  # after the sync: timestamps include the step's work
-        self.pool.lengths[active] += 1
-        self._tokens[active] = toks[active]
-        # feed the sampled tokens straight back; invalidated on churn below
-        self._dev = (toks_d, pos_d, active_d, temps_d, topk_d)
-        for slot in list(self._slot_req):
-            req = self._slot_req[slot]
-            tok = int(toks[slot])
-            req.out_tokens.append(tok)
-            if on_token is not None:
-                on_token(req, tok)
-            if self._finished(req, tok):
-                self._finish(slot, now)
+        with trace_span("serve.decode"):
+            active = self.pool.active_mask.copy()
+            with trace_span("serve.device_state"):
+                tokens_d, pos_d, active_d, temps_d, topk_d = \
+                    self._device_state()
+            decode = (
+                self._decode_greedy
+                if float(self._temps[active].max(initial=0.0)) <= 0.0
+                else self._decode_sample
+            )
+            with trace_span("serve.dispatch"):
+                toks_d, pos_d, self.pool.cache = decode(
+                    self.params, self.pool.cache, tokens_d, pos_d, active_d,
+                    temps_d, topk_d, self.rng, np.int32(self._step_no),
+                )
+            self._step_no += 1
+            with trace_span("serve.wait"):
+                toks = np.asarray(toks_d)  # the loop's one device→host sync
+            now = clock()  # after the sync: timestamps include the step
+            self.pool.lengths[active] += 1
+            self._tokens[active] = toks[active]
+            # feed the sampled tokens straight back; invalidated on churn
+            self._dev = (toks_d, pos_d, active_d, temps_d, topk_d)
+            with trace_span("serve.emit"):
+                for slot in list(self._slot_req):
+                    req = self._slot_req[slot]
+                    tok = int(toks[slot])
+                    req.out_tokens.append(tok)
+                    if on_token is not None:
+                        on_token(req, tok)
+                    if self._finished(req, tok):
+                        self._finish(slot, now)
 
     # ---- public API ------------------------------------------------------
     def submit(self, req: ServeRequest) -> ServeRequest:
@@ -495,67 +520,73 @@ class ContinuousEngine:
 
         with use_sharding(self.shard_ctx):
             while self.scheduler.has_pending() or self._slot_req:
-                now = clock()
-                if (not draining and should_drain is not None
-                        and should_drain()):
-                    draining = True
-                    drain_deadline = now + max(0.0, drain_grace_s)
-                    shed = self.scheduler.drain(now)
-                    self.telemetry.emit(
-                        "serve_drain", queued=len(shed),
-                        in_flight=len(self._slot_req),
-                        grace_s=max(0.0, drain_grace_s))
-                    for req in shed:
-                        self._terminal_removed(req)
-                if draining:
-                    # retries resubmitted after the drain started are shed
-                    for req in self.scheduler.drain(now):
-                        self._terminal_removed(req)
-                    if now >= drain_deadline and self._slot_req:
-                        for slot in list(self._slot_req):
-                            self._shed_slot(slot, now, "drain")
-                    admitted = []
-                else:
-                    # running requests past their latency budget free their
-                    # slot before this round's admissions claim it
-                    for slot in list(self._slot_req):
-                        req = self._slot_req[slot]
-                        if (req.timeout_s is not None
-                                and now - req.born_s > req.timeout_s):
-                            self._timeout_slot(slot, now)
-                    self._release_quarantined()
-                    admitted, removed = self.scheduler.admit(
-                        now, self.pool.n_free)
-                    for req in removed:
-                        self._terminal_removed(req)
-                for req in admitted:
-                    self._admit_one(req, clock, on_token)
-                if telem:
-                    queue_samples.append(self.scheduler.queue_depth(now))
-                    occ_samples.append(
-                        self.n_slots - self.pool.n_free
-                        - len(self._quarantined))
-                if not self._slot_req:
-                    if self._quarantined and self.scheduler.has_pending():
-                        # no decode steps will run while the pool idles, so
-                        # a quarantine can never expire on its own: release
-                        # early rather than deadlock the queue
-                        self._release_quarantined(force=True)
+                with trace_span("serve.turn") as turn:
+                    now = clock()
+                    if (not draining and should_drain is not None
+                            and should_drain()):
+                        draining = True
+                        drain_deadline = now + max(0.0, drain_grace_s)
+                        shed = self.scheduler.drain(now)
+                        self.telemetry.emit(
+                            "serve_drain", queued=len(shed),
+                            in_flight=len(self._slot_req),
+                            grace_s=max(0.0, drain_grace_s))
+                        for req in shed:
+                            self._terminal_removed(req)
+                    if draining:
+                        # retries resubmitted after the drain started are shed
+                        for req in self.scheduler.drain(now):
+                            self._terminal_removed(req)
+                        if now >= drain_deadline and self._slot_req:
+                            for slot in list(self._slot_req):
+                                self._shed_slot(slot, now, "drain")
+                        admitted = []
+                    else:
+                        with trace_span("serve.schedule"):
+                            # running requests past their latency budget
+                            # free their slot before this round's
+                            # admissions claim it
+                            for slot in list(self._slot_req):
+                                req = self._slot_req[slot]
+                                if (req.timeout_s is not None
+                                        and now - req.born_s > req.timeout_s):
+                                    self._timeout_slot(slot, now)
+                            self._release_quarantined()
+                            admitted, removed = self.scheduler.admit(
+                                now, self.pool.n_free)
+                            for req in removed:
+                                self._terminal_removed(req)
+                    for req in admitted:
+                        self._admit_one(req, clock, on_token)
+                    turn.set_metadata(admitted=len(admitted),
+                                      active=len(self._slot_req),
+                                      compiles=compile_count())
+                    if telem:
+                        queue_samples.append(self.scheduler.queue_depth(now))
+                        occ_samples.append(
+                            self.n_slots - self.pool.n_free
+                            - len(self._quarantined))
+                    if not self._slot_req:
+                        if self._quarantined and self.scheduler.has_pending():
+                            # no decode steps will run while the pool idles, so
+                            # a quarantine can never expire on its own: release
+                            # early rather than deadlock the queue
+                            self._release_quarantined(force=True)
+                            continue
+                        nxt = self.scheduler.next_arrival()
+                        if nxt is None:
+                            break
+                        offset += max(0.0, nxt - clock())
                         continue
-                    nxt = self.scheduler.next_arrival()
-                    if nxt is None:
-                        break
-                    offset += max(0.0, nxt - clock())
-                    continue
-                t_step = time.perf_counter()
-                if self.faults is not None:
-                    stall = self.faults.stall_s(self._run_steps)
-                    if stall > 0.0:
-                        time.sleep(stall)
-                self._step(clock, on_token)
-                self._watchdog(time.perf_counter() - t_step)
-                self._run_steps += 1
-                n_steps += 1
+                    t_step = time.perf_counter()
+                    if self.faults is not None:
+                        stall = self.faults.stall_s(self._run_steps)
+                        if stall > 0.0:
+                            time.sleep(stall)
+                    self._step(clock, on_token)
+                    self._watchdog(time.perf_counter() - t_step)
+                    self._run_steps += 1
+                    n_steps += 1
         self._release_quarantined(force=True)
 
         # exact, disjoint terminal accounting over everything submitted
@@ -574,6 +605,7 @@ class ContinuousEngine:
             stats = serving_stats(roster)
             stats.update(
                 decode_steps=n_steps,
+                compiles=compile_count(),
                 submitted=len(roster),
                 retries=self._n_retries,
                 quarantines=self._n_quarantines,

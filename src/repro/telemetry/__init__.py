@@ -13,7 +13,7 @@ from repro.telemetry.events import (
     validate_event,
 )
 from repro.telemetry.report import Check, CompareResult, RunReport
-from repro.telemetry.spans import SpanRecorder
+from repro.telemetry.spans import SpanRecorder, compile_count, trace_span
 from repro.telemetry.trust import HIST_EDGES, PER_LAYER_KEY, TrustRecorder, leaf_names
 
 __all__ = [
@@ -27,9 +27,11 @@ __all__ = [
     "SCHEMA_VERSION",
     "SpanRecorder",
     "TrustRecorder",
+    "compile_count",
     "config_hash",
     "leaf_names",
     "read_events",
     "run_provenance",
+    "trace_span",
     "validate_event",
 ]

@@ -30,6 +30,15 @@ Each closed span is one observation (``seconds`` / ``count`` items);
 ``summary()`` folds observations into count/total/mean/p50/max per name,
 and a wired :class:`~repro.telemetry.events.EventLog` receives one
 ``span`` event per close.
+
+Profiler spans.  :func:`trace_span` names a stretch of host work in a
+``jax.profiler`` trace, on the clock the device's ops are recorded on, so a
+gap on the device reads as the host work that was running in it.  Every
+recorder span opens one too (``span.`` + its name, ``count`` as an arg).
+With no profiler running a span costs a TraceMe check and one Python
+object: nothing is formatted and nothing syncs.  :func:`compile_count` is
+the process's running count of XLA compilations (persistent-cache hits
+excluded), fed by ``jax.monitoring``.
 """
 from __future__ import annotations
 
@@ -37,9 +46,38 @@ import contextlib
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.telemetry.events import EventLog
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+TRACE_PREFIX = "span."  # a recorder span ``step`` is ``span.step`` in a trace
+_compiles = 0
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    global _compiles
+    if event == COMPILE_EVENT:
+        _compiles += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def compile_count() -> int:
+    """XLA compilations in this process so far (cache hits excluded)."""
+    return _compiles
+
+
+def trace_span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """A host span in the profiler's trace, with ``args`` as its stats::
+
+        with trace_span("serve.admit", rid=req.rid) as sp:
+            ...
+            sp.set_metadata(slot=slot)   # args known only later
+    """
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 class SpanHandle:
@@ -61,7 +99,14 @@ class SpanRecorder:
     def __init__(self, log: Optional[EventLog] = None):
         self.log = log
         self._obs: Dict[str, List[tuple]] = {}  # name -> [(seconds, count)]
-        self._open: Dict[str, float] = {}
+        self._open: Dict[str, tuple] = {}  # name -> (t0, open trace span)
+        self._trace_names: Dict[str, str] = {}
+
+    def _trace_span(self, name: str) -> jax.profiler.TraceAnnotation:
+        full = self._trace_names.get(name)
+        if full is None:
+            full = self._trace_names[name] = TRACE_PREFIX + name
+        return trace_span(full)
 
     # -- core ----------------------------------------------------------------
     def observe(self, name: str, seconds: float, count: int = 1) -> None:
@@ -74,8 +119,6 @@ class SpanRecorder:
     @staticmethod
     def _sync(tree: Any) -> None:
         if tree is not None:
-            import jax
-
             jax.block_until_ready(tree)
 
     # -- scoped --------------------------------------------------------------
@@ -83,27 +126,37 @@ class SpanRecorder:
     def span(self, name: str, *, sync: Any = None, count: int = 1):
         self._sync(sync)
         handle = SpanHandle(count)
-        t0 = time.perf_counter()
-        try:
-            yield handle
-        finally:
-            for tree in handle._pending:
-                self._sync(tree)
-            self.observe(name, time.perf_counter() - t0, handle.count)
+        with self._trace_span(name) as traced:
+            t0 = time.perf_counter()
+            try:
+                yield handle
+            finally:
+                for tree in handle._pending:
+                    self._sync(tree)
+                traced.set_metadata(count=handle.count)
+                self.observe(name, time.perf_counter() - t0, handle.count)
 
     # -- phase-style ---------------------------------------------------------
     def start(self, name: str, *, sync: Any = None) -> None:
         """Open (or re-open) a named span; syncs, then stamps t0."""
+        stale = self._open.pop(name, None)
+        if stale is not None:  # re-opened: the old trace span ends here
+            stale[1].__exit__(None, None, None)
         self._sync(sync)
-        self._open[name] = time.perf_counter()
+        traced = self._trace_span(name)
+        traced.__enter__()
+        self._open[name] = (time.perf_counter(), traced)
 
     def stop(self, name: str, *, sync: Any = None, count: int = 1) -> float:
         """Close a named span opened by :meth:`start`; returns seconds."""
-        t0 = self._open.pop(name, None)
-        if t0 is None:
+        opened = self._open.pop(name, None)
+        if opened is None:
             raise ValueError(f"span {name!r} was never started")
+        t0, traced = opened
         self._sync(sync)
         dt = time.perf_counter() - t0
+        traced.set_metadata(count=count)
+        traced.__exit__(None, None, None)
         self.observe(name, dt, count)
         return dt
 

@@ -20,6 +20,11 @@
     projection and stream the CE over vocab chunks, so no ``(B, S, V)``
     logits tensor is ever materialized (see ``make_loss_fn`` / train/loss).
 
+Named scopes label the compiled step's ops for a profiler trace:
+``cast_params`` (the bf16 cast), ``jvp(model)`` (forward),
+``transpose(jvp(model))`` (backward) and ``optimizer`` (everything after
+the gradients).  They change op metadata only, not the program.
+
 ``make_optimizer`` wires the model's pytree metadata (weight-decay mask,
 trust-ratio mask, stacked-layer axes) into the paper's optimizers so that
 LAMB's layerwise semantics survive scanned parameter stacks.
@@ -226,8 +231,14 @@ def _microbatch_grads(loss_fn, params, batch, n_micro: int):
             b,
         )
 
+    def model_loss(p, b):
+        # the scope names the forward's ops ``jvp(model)`` and the
+        # backward's ``transpose(jvp(model))`` in the compiled program
+        with jax.named_scope("model"):
+            return loss_fn(p, b)
+
     def one(i):
-        (loss, metrics), g = jax.value_and_grad(loss_fn, has_aux=True)(
+        (loss, metrics), g = jax.value_and_grad(model_loss, has_aux=True)(
             params, slice_batch(batch, i)
         )
         g = jax.tree.map(lambda x: x.astype(jnp.float32), g)
@@ -288,7 +299,8 @@ def make_train_step(
     def cast_params(params):
         if compute_dtype is None:
             return params
-        return nn.cast_tree(params, jnp.dtype(compute_dtype))
+        with jax.named_scope("cast_params"):
+            return nn.cast_tree(params, jnp.dtype(compute_dtype))
 
     guard = tc.skip_nonfinite
 
@@ -305,6 +317,19 @@ def make_train_step(
         metrics = apply_loss_faults(dict(metrics), faults)
         metrics["grad_norm"] = _global_norm(grads)
         return grads, metrics
+
+    def with_gradients(update):
+        """step_fn(state, batch): the gradients, then ``update(state, grads,
+        metrics)`` under the ``optimizer`` scope, so that every op after
+        the gradients (the update, the finite guard, ``update_norm``, the
+        trust diagnostics) carries it in the compiled program."""
+
+        def step_fn(state: TrainState, batch) -> Tuple[TrainState, Dict]:
+            grads, metrics = grads_and_metrics(state.params, batch)
+            with jax.named_scope("optimizer"):
+                return update(state, grads, metrics)
+
+        return step_fn
 
     def finite_guard(grads, metrics):
         """Scalar ok-flag: everything the update would consume is finite."""
@@ -349,8 +374,7 @@ def make_train_step(
                 jnp.zeros([], jnp.int32),
             )
 
-        def step_fn(state: TrainState, batch) -> Tuple[TrainState, Dict]:
-            grads, metrics = grads_and_metrics(state.params, batch)
+        def update(state: TrainState, grads, metrics):
             if guard:
                 # the guard threads through the fused apply: every leaf
                 # where-selects old vs new in the same fused expression and
@@ -390,7 +414,7 @@ def make_train_step(
                 )
             return new_state, metrics
 
-        return init_fn, step_fn
+        return init_fn, with_gradients(update)
 
     opt = (
         optimizer
@@ -403,8 +427,7 @@ def make_train_step(
         return TrainState(params, opt.init(params), jnp.zeros([], jnp.int32),
                           jnp.zeros([], jnp.int32))
 
-    def step_fn(state: TrainState, batch) -> Tuple[TrainState, Dict]:
-        grads, metrics = grads_and_metrics(state.params, batch)
+    def update(state: TrainState, grads, metrics):
         updates, opt_state = opt.update(grads, state.opt_state, state.params)
         params = optim.apply_updates(state.params, updates)
         if guard:
@@ -436,7 +459,7 @@ def make_train_step(
                                    state.skipped)
         return new_state, metrics
 
-    return init_fn, step_fn
+    return init_fn, with_gradients(update)
 
 
 def _global_norm(tree):

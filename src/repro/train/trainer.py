@@ -56,7 +56,9 @@ from repro.telemetry import (
     EventLog,
     SpanRecorder,
     TrustRecorder,
+    compile_count,
     run_provenance,
+    trace_span,
 )
 from repro.telemetry.trust import PER_LAYER_KEY
 from repro.train.preempt import PreemptionHandler
@@ -269,7 +271,8 @@ class Trainer:
         scalars = {k: v for k, v in m.items()
                    if k not in ("step", "examples_seen", "wall_s", "stage")}
         ev = dict(step=m["step"], examples_seen=m["examples_seen"],
-                  wall_s=m["wall_s"], metrics=scalars)
+                  wall_s=m["wall_s"], metrics=scalars,
+                  compiles=compile_count())
         if "stage" in m:
             ev["stage"] = m["stage"]
         if n_steps:
@@ -420,9 +423,13 @@ class Trainer:
                     # only its own steps (async dispatch would otherwise
                     # attribute queued work to the wrong interval)
                     self.spans.start("step", sync=self.state)
-                batch = self._place_batch(next(data))
-                self.examples_seen += _batch_examples(batch)
-                self.state, metrics = self._step_fn(self.state, batch)
+                with jax.profiler.StepTraceAnnotation("train.step",
+                                                      step_num=i):
+                    with trace_span("train.input"):
+                        batch = self._place_batch(next(data))
+                    self.examples_seen += _batch_examples(batch)
+                    with trace_span("train.dispatch"):
+                        self.state, metrics = self._step_fn(self.state, batch)
                 since_log += 1
                 if supervisor is not None:
                     # the watchdog's cost: one blocking host fetch per step
@@ -643,9 +650,13 @@ class Trainer:
                 for i in range(stage.steps):
                     if telem and since_log == 0:
                         self.spans.start("step", sync=self.state)
-                    batch = self._place_batch(next(data))
-                    self.examples_seen += _batch_examples(batch)
-                    self.state, metrics = step_jit(self.state, batch)
+                    with jax.profiler.StepTraceAnnotation("train.step",
+                                                          step_num=i):
+                        with trace_span("train.input"):
+                            batch = self._place_batch(next(data))
+                        self.examples_seen += _batch_examples(batch)
+                        with trace_span("train.dispatch"):
+                            self.state, metrics = step_jit(self.state, batch)
                     since_log += 1
                     if (i + 1) % self.log_every == 0 or i == stage.steps - 1:
                         m, per_layer = self._host_metrics(metrics)
