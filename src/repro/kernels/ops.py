@@ -62,8 +62,8 @@ def kernel_calls(hlo: str, name: str) -> List[str]:
 def pallas_spec_ok(spec) -> bool:
     """True if a parameter with this PartitionSpec can feed the Pallas kernel.
 
-    The fused kernel works on a padded ``(layers, R, 128)`` view of the
-    whole leaf — valid only for replicated leaves.  A leaf
+    The fused kernel works on a ``(layers, rows, C)`` view of the whole
+    leaf — valid only for replicated leaves.  A leaf
     sharded on any mesh axis (FSDP ``embed``, TP ``heads``/``ff``) must take
     the fused-XLA ``lamb_update_ref`` path instead, where GSPMD inserts the
     collectives that keep the per-layer ‖x‖/‖u‖ trust-ratio reductions
@@ -117,16 +117,21 @@ def fused_lamb_apply(
     with_aux: bool = False,
     ok: Optional[jnp.ndarray] = None,
 ) -> Tuple[Any, ...]:
-    """One fused LAMB step over a whole pytree: (params', mu', nu').
+    """One fused LAMB step over a whole pytree:
+    (params', mu', nu', update_norm).
+
+    ``update_norm`` is ‖params' − params‖ over every leaf (float32), the
+    step's ``update_norm`` metric; the kernel sums it as it writes params'
+    over params, where nothing could read the old weights afterwards.
 
     ``ok`` (scalar bool, optional) is the non-finite guard: when False the
     computed update is discarded leaf-by-leaf — params and both moments
-    where-select back to their inputs inside the same fused expression, so
-    a skipped step costs no extra memory traffic and the state comes back
-    bit-identical.  The caller gates the counters (see
-    :func:`make_fused_lamb_step`).
+    come back bit-identical (the kernels write their inputs back; the XLA
+    form where-selects them in the same fused expression), so a skipped
+    step costs no extra memory traffic.  The caller gates the counters
+    (see :func:`make_fused_lamb_step`).
 
-    ``with_aux=True`` appends a fourth output: a pytree shaped like
+    ``with_aux=True`` appends a fifth output: a pytree shaped like
     ``params`` of the *applied* per-layer trust ratios (each backend's
     ``return_ratio`` aux — the telemetry recorder's source of truth, no
     recompute from deltas).
@@ -166,7 +171,7 @@ def fused_lamb_apply(
             is_leaf=lambda s: s is None or isinstance(s, PartitionSpec),
         )
 
-    xs, ms, vs, rs = [], [], [], []
+    xs, ms, vs, rs, usq = [], [], [], [], []
     for p, g, m, v, axis, wd_on, tr_on, spec in zip(
         p_l, g_l, m_l, v_l, la_l, wm_l, tm_l, sp_l
     ):
@@ -185,9 +190,16 @@ def fused_lamb_apply(
                 layer_axis=axis, apply_trust=bool(tr_on),
                 return_ratio=with_aux,
             )
+            x_new, m_new, v_new = out[:3]
+            if ok is not None:
+                x_new = jnp.where(ok, x_new, p)
+                m_new = jnp.where(ok, m_new, m)
+                v_new = jnp.where(ok, v_new, v)
+            usq.append(jnp.sum(jnp.square(
+                x_new.astype(jnp.float32) - p.astype(jnp.float32))))
         else:
             out = lamb_update(
-                p, g, m, v, count, lr_t,
+                p, g, m, v, count, lr_t, ok,
                 lr=1.0, b1=b1, b2=b2, eps=eps,
                 weight_decay=weight_decay if wd_on else 0.0,
                 phi_lo=None if phi_bounds is None else phi_bounds[0],
@@ -196,11 +208,8 @@ def fused_lamb_apply(
                 interpret=leaf_mode == "interpret",
                 return_ratio=with_aux,
             )
-        x_new, m_new, v_new = out[0], out[1], out[2]
-        if ok is not None:
-            x_new = jnp.where(ok, x_new, p)
-            m_new = jnp.where(ok, m_new, m)
-            v_new = jnp.where(ok, v_new, v)
+            x_new, m_new, v_new = out[:3]
+            usq.append(out[-1])
         xs.append(x_new)
         ms.append(m_new)
         vs.append(v_new)
@@ -208,7 +217,8 @@ def fused_lamb_apply(
             rs.append(out[3])
 
     unflat = jax.tree_util.tree_unflatten
-    result = (unflat(treedef, xs), unflat(treedef, ms), unflat(treedef, vs))
+    result = (unflat(treedef, xs), unflat(treedef, ms), unflat(treedef, vs),
+              jnp.sqrt(jnp.sum(jnp.stack(usq))))
     if with_aux:
         result += (unflat(treedef, rs),)
     return result
@@ -246,15 +256,16 @@ def make_fused_lamb_step(
     """The single stateful fused-LAMB core shared by the transform wrapper
     and the jit'd train step's direct path.
 
-    Returns ``step(params, grads, state) -> (new_params, new_state)``:
-    clip → count/sched_count advance → lr(sched_count) → fused apply, in
-    that order.  ``param_specs`` propagates the per-leaf sharded-parameter
-    fallback (see :func:`fused_lamb_apply`).  With ``with_aux`` the step
-    returns ``(new_params, new_state, trust_ratios)`` — the applied
-    per-layer ratios threaded out for the telemetry recorder.  ``ok``
+    Returns ``step(params, grads, state) -> (new_params, new_state,
+    update_norm)``: clip → count/sched_count advance → lr(sched_count) →
+    fused apply, in that order; ``update_norm`` is ‖new_params − params‖.
+    ``param_specs`` propagates the per-leaf sharded-parameter fallback
+    (see :func:`fused_lamb_apply`).  With ``with_aux`` the step appends
+    ``trust_ratios`` — the applied per-layer ratios threaded out for the
+    telemetry recorder.  ``ok``
     (scalar bool) is the train step's non-finite guard: when False the
-    apply where-selects everything back to its inputs and *neither counter
-    advances* — the skipped step leaves the schedule position untouched.
+    apply returns everything as it was and *neither counter advances* —
+    the skipped step leaves the schedule position untouched.
     Invariant: keeping this sequence in one place is what guarantees
     fused-direct vs transform parity.
     """
@@ -279,9 +290,7 @@ def make_fused_lamb_step(
         new_params, new_mu, new_nu = out[:3]
         new_state = FusedLambState(count, state.sched_count + adv,
                                    new_mu, new_nu)
-        if with_aux:
-            return new_params, new_state, out[3]
-        return new_params, new_state
+        return (new_params, new_state) + tuple(out[3:])
 
     return step
 
@@ -326,7 +335,7 @@ def fused_lamb(
     def update(grads, state, params=None):
         if params is None:
             raise ValueError("fused_lamb requires params")
-        new_params, new_state = step(params, grads, state)
+        new_params, new_state, _ = step(params, grads, state)
         # Return *updates* (delta) so apply_updates composes like other opts.
         updates = jax.tree.map(
             lambda new, old: (new.astype(jnp.float32) - old.astype(jnp.float32)).astype(old.dtype),

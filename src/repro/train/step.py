@@ -385,9 +385,9 @@ def make_train_step(
             else:
                 out = fused_step(state.params, grads, state.opt_state)
             params, opt_state = out[0], out[1]
-            # same metric schema as the unfused path; the subtraction fuses
-            # into the norm reduction (no materialized delta tree)
-            metrics["update_norm"] = _delta_norm(params, state.params)
+            # same metric schema as the unfused path; the kernels sum
+            # ‖params' − params‖ as they write params' over params
+            metrics["update_norm"] = out[2]
             if tc.log_trust_ratios or record:
                 updates = jax.tree.map(
                     lambda new, old: new.astype(jnp.float32)
@@ -397,9 +397,9 @@ def make_train_step(
                 if tc.log_trust_ratios:
                     metrics.update(trust_diag(state.params, updates))
                 if record:
-                    # out[2] = the kernels' applied per-layer ratios (aux)
+                    # out[3] = the kernels' applied per-layer ratios (aux)
                     metrics[PER_LAYER_KEY] = per_layer_records(
-                        state.params, updates, applied_ratio=out[2]
+                        state.params, updates, applied_ratio=out[3]
                     )
             if guard:
                 adv = ok.astype(jnp.int32)
@@ -464,13 +464,4 @@ def make_train_step(
 
 def _global_norm(tree):
     sq = [jnp.sum(jnp.square(x.astype(jnp.float32))) for x in jax.tree.leaves(tree)]
-    return jnp.sqrt(jnp.sum(jnp.stack(sq)))
-
-
-def _delta_norm(new_tree, old_tree):
-    """Global L2 norm of (new - old) without materializing the delta tree."""
-    sq = [
-        jnp.sum(jnp.square(n.astype(jnp.float32) - o.astype(jnp.float32)))
-        for n, o in zip(jax.tree.leaves(new_tree), jax.tree.leaves(old_tree))
-    ]
     return jnp.sqrt(jnp.sum(jnp.stack(sq)))

@@ -23,10 +23,21 @@ LAMB_SHAPES = [
     ((2, 64, 32), 0),
     ((1, 9000), 0),
     ((3, 4096), 0),
-    # several default-size tiles per layer with a padded last tile: the
-    # per-layer norm sums accumulate across tiles
+    # a minor dim too wide for one tile: several column tiles per layer, a
+    # ragged last one; the per-layer norm sums accumulate across tiles
     ((2, 140000), 0),
     ((140000,), None),
+    # a lane-aligned dim that is not the last moved minor (bert's
+    # (L, d, heads, head_dim) at small widths), one ragged row tile
+    ((2, 384, 5, 24), 0),
+    # unstacked, several row tiles, a ragged last one (bert's embedding)
+    ((1100, 256), None),
+    # no dim a multiple of 128 (smollm's 960-wide leaves): the minor dim
+    # is a whole tile's width, not a multiple of 128
+    ((2, 120, 5, 24), 0),
+    # the first dim minor and too wide for a tile: ragged row and column
+    # tiles both (smollm's (49152, 960) embedding)
+    ((9000, 40), None),
 ]
 
 
@@ -40,7 +51,7 @@ def test_lamb_kernel_matches_ref(shape, axis, dtype):
     kw = dict(lr=0.01, b1=0.9, b2=0.999, eps=1e-6, weight_decay=0.01)
     x1, m1, v1 = lamb_update(
         x, g, m, v, jnp.asarray(5), layer_axis=axis, interpret=True, **kw
-    )
+    )[:3]
     x2, m2, v2 = lamb_update_ref(x, g, m, v, step=5, layer_axis=axis, **kw)
     tol = dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 else dict(
         rtol=3e-5, atol=3e-6)
@@ -63,10 +74,98 @@ def test_lamb_kernel_phi_bounds_and_no_trust():
             kern_kw.update(phi_lo=kw["phi_bounds"][0], phi_hi=kw["phi_bounds"][1])
         else:
             kern_kw.update(apply_trust=False)
-        x1, _, _ = lamb_update(x, g, m, v, jnp.asarray(1), **kern_kw)
+        x1 = lamb_update(x, g, m, v, jnp.asarray(1), **kern_kw)[0]
         x2, _, _ = lamb_update_ref(x, g, m, v, **ref_kw)
         np.testing.assert_allclose(np.asarray(x1), np.asarray(x2),
                                    rtol=3e-5, atol=3e-6)
+
+
+def _lamb_inputs(shape, seed=0):
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    g = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    m = jnp.asarray(rng.standard_normal(shape), jnp.float32) * 0.1
+    v = jnp.abs(jnp.asarray(rng.standard_normal(shape), jnp.float32)) * 0.01
+    return x, g, m, v
+
+
+@pytest.mark.parametrize("shape,axis,wd,trust", [
+    ((2, 384, 5, 24), 0, 0.01, True),    # minor dim moved last, stacked
+    ((96, 256), None, 0.0, False),       # unstacked, no decay, no trust
+    ((2, 120, 5, 24), 0, 0.0, True),     # minor dim not a multiple of 128
+    ((300,), None, 0.01, False),         # unstacked, one ragged tile
+], ids=["lane-stacked", "lane-plain", "odd-stacked", "odd-plain"])
+def test_lamb_kernel_ratio_and_update_sq(shape, axis, wd, trust):
+    """The aux outputs: the applied trust ratio equals the oracle's, and
+    the update's squared norm is ‖x' − x‖² of the kernel's own outputs."""
+    x, g, m, v = _lamb_inputs(shape)
+    kw = dict(lr=0.01, weight_decay=wd, apply_trust=trust, layer_axis=axis)
+    x1, m1, v1, r1, usq = lamb_update(
+        x, g, m, v, jnp.asarray(3), interpret=True, return_ratio=True, **kw)
+    x2, m2, v2, r2 = lamb_update_ref(x, g, m, v, step=3, return_ratio=True,
+                                     **kw)
+    np.testing.assert_allclose(np.asarray(x1), np.asarray(x2),
+                               rtol=3e-5, atol=3e-6)
+    np.testing.assert_allclose(np.asarray(m1), np.asarray(m2), rtol=3e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), rtol=3e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(r1), np.asarray(r2), rtol=1e-5)
+    assert r1.shape == ((shape[0],) if axis == 0 else ())
+    want = np.sum(np.square(np.asarray(x1, np.float64) - np.asarray(x)))
+    np.testing.assert_allclose(float(usq), want, rtol=1e-4)
+
+
+@pytest.mark.parametrize("shape,axis", [
+    ((2, 384, 5, 24), 0), ((1100, 256), None), ((2, 120, 5, 24), 0),
+    ((9000, 40), None),
+], ids=["lane-stacked", "lane-ragged", "odd", "ragged-columns"])
+def test_lamb_kernel_guard_skip_is_bit_identical(shape, axis):
+    """ok=False writes every input back unchanged, non-finite gradients
+    and all; ok=True is the unguarded update."""
+    x, g, m, v = _lamb_inputs(shape, seed=1)
+    g_bad = g.at[(0,) * len(shape)].set(jnp.nan)
+    kw = dict(lr=0.01, layer_axis=axis, interpret=True)
+    x1, m1, v1, usq = lamb_update(x, g_bad, m, v, jnp.asarray(2),
+                                  ok=jnp.asarray(False), **kw)
+    for got, want in ((x1, x), (m1, m), (v1, v)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert float(usq) == 0.0
+    on = lamb_update(x, g, m, v, jnp.asarray(2), ok=jnp.asarray(True), **kw)
+    free = lamb_update(x, g, m, v, jnp.asarray(2), **kw)
+    for a, b in zip(on, free):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("mode", ["xla", "interpret"])
+def test_fused_lamb_apply_update_norm_and_guard(mode):
+    """Both backends: ``update_norm`` is ‖params' − params‖ over the tree,
+    and a guarded skip returns params and moments bit-identical with a
+    zero update norm."""
+    from repro.kernels import fused_lamb_apply
+
+    params = {"w": _lamb_inputs((2, 256, 4, 32))[0],
+              "emb": _lamb_inputs((200, 128), seed=2)[0],
+              "b": _lamb_inputs((8,), seed=3)[0]}
+    grads = jax.tree.map(lambda p: p * 0.5 + 0.1, params)
+    zeros = jax.tree.map(jnp.zeros_like, params)
+    la = {"w": 0, "emb": None, "b": None}
+    kw = dict(layer_axes=la, mode=mode)
+    x, m, v, unorm = fused_lamb_apply(params, grads, zeros, zeros,
+                                      jnp.asarray(1), jnp.asarray(1e-2), **kw)
+    want = np.sqrt(sum(np.sum(np.square(np.asarray(a, np.float64)
+                                        - np.asarray(b)))
+                       for a, b in zip(jax.tree.leaves(x),
+                                       jax.tree.leaves(params))))
+    np.testing.assert_allclose(float(unorm), want, rtol=1e-4)
+    bad = jax.tree.map(lambda g: g.at[(0,) * g.ndim].set(jnp.inf), grads)
+    x, m, v, unorm = fused_lamb_apply(params, bad, zeros, zeros,
+                                      jnp.asarray(1), jnp.asarray(1e-2),
+                                      ok=jnp.asarray(False), **kw)
+    for got, want in ((x, params), (m, zeros), (v, zeros)):
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert float(unorm) == 0.0
 
 
 def test_fused_lamb_transform_equals_core_lamb():

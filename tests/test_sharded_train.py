@@ -252,11 +252,11 @@ def test_fused_lamb_apply_sharded_specs_fall_back_to_xla(mode):
     grads = jax.tree.map(jnp.ones_like, params)
     zeros = jax.tree.map(jnp.zeros_like, params)
     specs = {"w": P("data", None), "b": P("data")}
-    x_kern, _, _ = fused_lamb_apply(
+    x_kern, _, _, _ = fused_lamb_apply(
         params, grads, zeros, zeros, jnp.asarray(1), jnp.asarray(1e-3),
         mode=mode, param_specs=specs,
     )
-    x_xla, _, _ = fused_lamb_apply(
+    x_xla, _, _, _ = fused_lamb_apply(
         params, grads, zeros, zeros, jnp.asarray(1), jnp.asarray(1e-3),
         mode="xla",
     )

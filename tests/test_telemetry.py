@@ -160,7 +160,7 @@ def test_fused_aux_ratio_matches_numpy_oracle():
         layer_axes={"w": 0, "b": None},
         grad_clip_norm=None, mode="xla", with_aux=True,
     )
-    _, _, trust = jax.jit(step)(params, grads, fused_lamb_init(params))
+    _, _, _, trust = jax.jit(step)(params, grads, fused_lamb_init(params))
 
     want_w = _lamb_oracle_ratio(params["w"], grads["w"], eps=eps, wd=wd,
                                 layer_axis=0)

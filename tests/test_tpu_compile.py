@@ -11,8 +11,10 @@ one process at a time may load the TPU library, and pytest-xdist workers
 import every test file.  With ``--dist loadfile`` the worker that gets this
 file is the only one that loads it.
 """
+import dataclasses
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,15 +23,21 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
+from repro.configs.base import TrainConfig
+from repro.data import make_batch
 from repro.kernels import (
     flash_attention,
     flash_sdpa,
     fused_ce,
+    fused_lamb_apply,
     kernel_calls,
     lamb_update,
 )
+from repro.models import build_model
 from repro.sharding import ShardCtx, use_sharding
 from repro.train.loss import fused_cross_entropy
+from repro.train.step import make_train_step
 
 HEADS, HEAD_DIM, D_MODEL, VOCAB, LAYERS = 16, 64, 1024, 30522, 24
 BATCH = 8
@@ -113,12 +121,15 @@ def test_fused_ce_fwd_bwd_compiles(one_chip):
     assert kernel_calls(hlo, "fused_ce_dw")
 
 
-@pytest.mark.parametrize("shape,layer_axis", [
-    ((LAYERS, D_MODEL, HEADS, HEAD_DIM), 0),   # stacked attention weight
-    ((LAYERS, D_MODEL), 0),                    # stacked bias
-    ((VOCAB, D_MODEL), None),                  # unstacked embedding
-], ids=["stacked", "bias", "embedding"])
-def test_lamb_update_compiles(one_chip, shape, layer_axis):
+@pytest.mark.parametrize("shape,layer_axis,tc", [
+    ((LAYERS, D_MODEL, HEADS, HEAD_DIM), 0, D_MODEL),  # stacked attention
+    ((LAYERS, D_MODEL), 0, D_MODEL),                   # stacked bias
+    ((VOCAB, D_MODEL), None, D_MODEL),                 # unstacked embedding
+    ((2, 64, 32768), 0, 8192),      # minor dim too wide: column tiles
+    ((2, 15, 64, 960), 0, 960),     # smollm's attn.wo: a 960-wide tile
+    ((49152, 960), None, 8192),     # smollm's embedding, 49152 minor
+], ids=["stacked", "bias", "embedding", "wide", "odd", "wide-first"])
+def test_lamb_update_compiles(one_chip, shape, layer_axis, tc):
     leaf = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     step = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
 
@@ -129,8 +140,90 @@ def test_lamb_update_compiles(one_chip, shape, layer_axis):
     hlo = _compile(update, leaf, leaf, leaf, leaf, step)
     layers = shape[0] if layer_axis == 0 else 1
     (moments,) = kernel_calls(hlo, "lamb_moments")
-    assert f"f32[{layers},8,128]" in moments   # per-layer partial sums
+    assert f"f32[{layers},8,{tc}]" in moments   # per-layer partial sums
     assert kernel_calls(hlo, "lamb_apply")
+
+
+RELAYOUT = re.compile(r"copy|pad|slice|reshape|transpose")
+
+
+def _weight_sized_ops(hlo: str, scope=None, floor: int = 1 << 20) -> list:
+    """Ops of the entry computation with an array of ``floor`` or more
+    elements, other than the LAMB kernels and ops that move no data.  With
+    ``scope``, only those under that named scope whose op or fusion is a
+    relayout (copy, pad, slice, reshape or transpose)."""
+    entry = re.search(r"^ENTRY .*?^}", hlo, re.S | re.M).group(0)
+    found = []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", line)
+        if not m or m.group(3) in ("parameter", "get-tuple-element",
+                                   "bitcast", "tuple", "constant"):
+            continue
+        if "/lamb_moments/" in line or "/lamb_apply/" in line:
+            continue
+        if scope is not None and (scope not in line
+                                  or not RELAYOUT.search(m.group(1))):
+            continue
+        sizes = [math.prod(int(d) for d in dims.split(",") if d)
+                 for dims in re.findall(r"\[([\d,]*)\]", m.group(2))]
+        if max(sizes, default=0) >= floor:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("arch,n_leaves", [
+    ("bert-large", 13),
+    ("smollm-360m", 11),   # 960 wide: no dim of wq, wk, wv, wo is 128-aligned
+])
+def test_fused_lamb_apply_in_place_compiles(one_chip, arch, n_leaves):
+    """A model's leaves through the fused apply, params, m and v donated
+    as the train step donates its state: every weight-sized op is a LAMB
+    kernel.  No relayout of a leaf to the kernels' view or back (copy,
+    reshape or transpose fusion), no pad or slice of a ragged leaf, no
+    copy of a donated input the kernels write over."""
+    model = build_model(get_config(arch))
+    shapes = jax.eval_shape(model.init, jax.random.key(0))
+    leaves = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, jnp.float32, sharding=one_chip), shapes)
+    assert len(jax.tree.leaves(leaves)) == n_leaves
+    scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=one_chip)  # noqa: E731
+
+    def apply(p, g, m, v, count, lr):
+        return fused_lamb_apply(
+            p, g, m, v, count, lr, wd_mask=model.wd_mask(),
+            trust_mask=model.trust_mask(), layer_axes=model.layer_axes(),
+            mode="pallas")
+
+    compiled = jax.jit(apply, donate_argnums=(0, 2, 3)).lower(
+        leaves, leaves, leaves, leaves, scalar(jnp.int32),
+        scalar(jnp.float32)).compile()
+    hlo = compiled.as_text()
+    assert len(kernel_calls(hlo, "lamb_apply")) == n_leaves
+    assert _weight_sized_ops(hlo) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20) * 4
+
+
+def test_train_step_optimizer_relayouts_no_leaf(one_chip):
+    """bert-large's fused-LAMB train step, state donated as the Trainer
+    donates it, at 2 layers and batch 4 × 128 (the optimizer's ops depend
+    on neither): under the ``optimizer`` scope no weight-sized copy, pad,
+    slice, reshape or transpose is left, only clipping's multiplies and
+    the kernels."""
+    cfg = dataclasses.replace(get_config("bert-large"), n_layers=2)
+    model = build_model(cfg)
+    tc = TrainConfig(optimizer="lamb", use_fused_lamb=True,
+                     fused_backend="pallas", precision="bf16",
+                     grad_clip_norm=1.0)
+    init, step = make_train_step(model, tc)
+    place = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        np.shape(a), a.dtype, sharding=one_chip)
+    state = jax.tree.map(place, jax.eval_shape(init, jax.random.key(0)))
+    batch = jax.tree.map(lambda a: place(np.asarray(a)),
+                         make_batch(cfg, np.random.default_rng(0), 4, 128))
+    hlo = jax.jit(step, donate_argnums=(0,)).lower(
+        state, batch).compile().as_text()
+    assert len(kernel_calls(hlo, "lamb_apply")) == 13
+    assert _weight_sized_ops(hlo, scope="/optimizer/") == []
 
 
 def test_flash_sharded_data4_runs_per_chip_batch(mesh4):
