@@ -86,7 +86,7 @@ def serve_readings(cell, args) -> dict:
         else:
             prog.reseed(seed)
         rec = S.run_window(prog, seed, args.seconds)
-        stats = S.window_stats(rec, args.seconds, cell.config)
+        stats = S.window_stats(rec, args.seconds)
         sample = S.check_sample(rec, seed, cell.traffic["check_tokens"])
         if seed in seeds(args.seeds):
             out["program"][seed] = dict(S.served_gaps(cell, seed, sample),

@@ -4,6 +4,7 @@ device description, traced sections, per-layer metric reading, checks
 against limits and the result line."""
 from __future__ import annotations
 
+import dataclasses
 import gc
 import json
 import math
@@ -23,26 +24,32 @@ from chipbench.traffic import seed_words
 
 
 def program_config(config: dict, traffic: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
+    """The program's ``ModelConfig`` for a configuration file: the
+    published keys mapped to its fields, then every key of the file's
+    ``program`` block under its own field name.  A ``program`` key that is
+    no field of ``ModelConfig`` is an error that names it.  Widths given
+    in both places are held together by ``check_layout``, which compares
+    the reference's weight layout, built from the published keys, with the
+    program's."""
     from repro.configs.base import ModelConfig
 
-    p = config["program"]
+    program = config["program"]
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    unknown = sorted(set(program) - fields)
+    if unknown:
+        raise KeyError(f"{config['name']}: program key(s) {unknown} are not "
+                       "fields of the program's ModelConfig")
     heads = config["num_attention_heads"]
-    return ModelConfig(
-        name=config["name"], family=p["family"],
-        n_layers=config["num_hidden_layers"], d_model=config["hidden_size"],
-        n_heads=heads, n_kv_heads=config.get("num_key_value_heads", heads),
+    published = dict(
+        name=config["name"], n_layers=config["num_hidden_layers"],
+        d_model=config["hidden_size"], n_heads=heads,
+        n_kv_heads=config.get("num_key_value_heads", heads),
         d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
-        rope_theta=config["rope_theta"], causal=p["causal"],
-        norm_type=p["norm_type"], act_fn=p["act_fn"],
-        gated_mlp=p["gated_mlp"], use_rope=p["use_rope"],
-        tie_embeddings=p["tie_embeddings"],
-        mask_ratio=p.get("mask_ratio", 0.0),
-        use_flash_kernel=p.get("use_flash_kernel", False),
-        use_fused_ce_head=p.get("use_fused_ce_head", False),
-        param_dtype=p["param_dtype"], activation_dtype=p["activation_dtype"],
-        mlm_max_predictions=traffic.get("max_predictions"),
-    )
+        rope_theta=config["rope_theta"],
+        mlm_max_predictions=traffic.get("max_predictions"))
+    if "head_dim" in config:
+        published["head_dim"] = config["head_dim"]
+    return ModelConfig(**{**published, **program})
 
 
 def key_data(seed: int) -> jnp.ndarray:

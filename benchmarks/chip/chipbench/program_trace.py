@@ -9,13 +9,18 @@ a run and kept on the ``Ctx``, as plain data (what the tests run on):
 
     {"window": [t0_ns, t1_ns],                 # the traced span, host clock
      "spans": [[name, start_ns, dur_ns, {arg: value}], ...],
-     "devices": {"0": {"ops": [[name, start_ns, dur_ns, scope], ...],
+     "devices": {"0": {"ops": [[name, start_ns, dur_ns, scope, category],
+                               ...],
                        "modules": [[name, start_ns, dur_ns], ...]}, ...}}
 
 ``scope`` is the op's name stack (``jit(step_fn)/transpose(jvp(model))/
 ...``): on a TPU v5e it is the ``tf_op`` stat of the op's event metadata,
-which ``ProfileData`` does not expose, so ``op_scopes`` reads the xplane's
+which ``ProfileData`` does not expose, so ``op_stats`` reads the xplane's
 protobuf for it.  An op the compiler made (a layout copy) has none.
+``category`` is the ``hlo_category`` stat of the same metadata (``loop
+fusion``, ``all-gather``, ``collective-permute-done``): a reduce-scatter
+the compiler fused is a ``fusion.N`` known by its category alone
+(``all-reduce-scatter fusion``).
 A program without the spans or scopes gives no number: every reduction
 here returns None then.
 """
@@ -96,26 +101,30 @@ def _xplane_messages():
         pool.FindMessageTypeByName("chipbench_xplane.XSpace"))
 
 
-def op_scopes(xplane_path: str) -> Dict[str, Dict[str, str]]:
-    """{device plane: {op event name: name stack}}: the ``tf_op`` stat of
-    each op's event metadata (its HLO ``op_name``; a referenced stat is
-    resolved), where it has one."""
+OP_STATS = ("tf_op", "hlo_category")
+
+
+def op_stats(xplane_path: str) -> Dict[str, Dict[str, Dict[str, str]]]:
+    """{device plane: {op event name: {stat: value}}}: the ``tf_op`` (its
+    HLO ``op_name``) and ``hlo_category`` stats of each op's event
+    metadata, a referenced stat resolved, where it has them."""
     space = _xplane_messages()()
     with open(xplane_path, "rb") as f:
         space.ParseFromString(f.read())
-    out: Dict[str, Dict[str, str]] = {}
+    out: Dict[str, Dict[str, Dict[str, str]]] = {}
     for plane in space.planes:
         if not tr.DEVICE_PLANE.match(plane.name):
             continue
         stat_names = {e.key: e.value.name for e in plane.stat_metadata}
-        scopes = out.setdefault(plane.name, {})
+        ops = out.setdefault(plane.name, {})
         for entry in plane.event_metadata:
             md = entry.value
             for st in md.stats:
-                if stat_names.get(st.metadata_id) == "tf_op":
-                    scope = st.str_value or stat_names.get(st.ref_value, "")
-                    if scope and not scopes.get(md.name):
-                        scopes[md.name] = scope.rstrip(":")
+                name = stat_names.get(st.metadata_id)
+                if name in OP_STATS:
+                    value = st.str_value or stat_names.get(st.ref_value, "")
+                    if value and not ops.get(md.name, {}).get(name):
+                        ops.setdefault(md.name, {})[name] = value.rstrip(":")
     return out
 
 
@@ -131,7 +140,7 @@ def read(xplane_path: str, window) -> dict:
 
     spans: List[list] = []
     devices: Dict[str, dict] = {}
-    scopes = op_scopes(xplane_path)
+    stats = op_stats(xplane_path)
     for plane in ProfileData.from_file(xplane_path).planes:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
@@ -146,12 +155,15 @@ def read(xplane_path: str, window) -> dict:
         if not m:
             continue
         dev = devices.setdefault(m.group(1), {"ops": [], "modules": []})
-        scope = scopes.get(plane.name, {})
+        meta = stats.get(plane.name, {})
         for line in plane.lines:
             if line.name == "XLA Ops":
-                dev["ops"] += [[tr.op_name(e.name), e.start_ns,
-                                e.duration_ns, scope.get(e.name, "")]
-                               for e in line.events if inside(e)]
+                for e in line.events:
+                    if inside(e):
+                        st = meta.get(e.name, {})
+                        dev["ops"].append([tr.op_name(e.name), e.start_ns,
+                                           e.duration_ns, st.get("tf_op", ""),
+                                           st.get("hlo_category", "")])
             elif line.name == "XLA Modules":
                 dev["modules"] += [[e.name, e.start_ns, e.duration_ns]
                                    for e in line.events if inside(e)]

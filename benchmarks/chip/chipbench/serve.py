@@ -29,7 +29,6 @@ import numpy as np
 
 from chipbench import BENCH_DIR
 from chipbench import common as cm
-from chipbench import counts
 from chipbench import trace as tr
 from chipbench.traffic import rng, serve_schedule
 
@@ -155,8 +154,10 @@ def run_window(program: Program, seed: int, seconds: float,
             "t_gen": t_gen}
 
 
-def window_stats(rec: dict, seconds: float, cfg: dict) -> dict:
-    """End-to-end numbers and counters of one window, from its record."""
+def window_stats(rec: dict, seconds: float) -> dict:
+    """End-to-end numbers and counters of one window, from its record;
+    ``served`` lists (prompt length, index in the output) of every token
+    emitted in the window, from which ``serve_mfu`` counts operations."""
     from repro.serve.scheduler import RequestStatus
 
     w0, w1 = rec["w0"], rec["w1"]
@@ -174,26 +175,23 @@ def window_stats(rec: dict, seconds: float, cfg: dict) -> dict:
     # first tokens, each the end of an admission: a gap between two tokens
     # of one request spans the decode step and the admissions between them
     firsts = np.sort([ts[0] for ts in rec["times"].values() if ts])
-    gaps, gap_admits, emitted, flops = [], [], 0, 0.0
+    gaps, gap_admits, served = [], [], []
     for rid, ts in rec["times"].items():
         plen = len(reqs[rid].prompt)
         for j, x in enumerate(ts):
             if not (w0 <= x < w1):
                 continue
-            emitted += 1
-            if j == 0:
-                flops += counts.prefill_flops(cfg, plen)
-            else:
+            served.append((plen, j))
+            if j:
                 gaps.append(x - ts[j - 1])
                 gap_admits.append(int(np.searchsorted(firsts, x)
                                       - np.searchsorted(firsts, ts[j - 1],
                                                         side="right")))
-                flops += counts.decode_token_flops(cfg, plen + j)
     return {
         "attempted": len(rec["in_window"]), "failed": failed,
         "ttft_s": ttft, "itl_s": gaps, "itl_admits": gap_admits,
         "queue_wait_s": waits,
-        "emitted": emitted, "flops": flops, "seconds": seconds,
+        "emitted": len(served), "served": served, "seconds": seconds,
         "fast_forwards": rec["fast_forwards"],
     }
 
@@ -271,7 +269,7 @@ def served_gaps(cell, seed: int, sample, mm=None, lower=None) -> dict:
 
 
 def counters_for(stats: dict) -> dict:
-    return {"queue_wait_s": stats["queue_wait_s"], "flops": stats["flops"],
+    return {"queue_wait_s": stats["queue_wait_s"], "served": stats["served"],
             "seconds": stats["seconds"]}
 
 
@@ -296,7 +294,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     if trace and not all(program.span_calls.values()):
         raise RuntimeError(f"the engine no longer calls the methods the "
                            f"traced spans wrap: {program.span_calls}")
-    stats = window_stats(rec, seconds, cell.config)
+    stats = window_stats(rec, seconds)
     device = cm.device_info(devices[:cell.chips])
     late = max([0.0] + [rec["times"][rid][0] - rec["w1"]
                         for rid in rec["in_window"] if rec["times"][rid]])

@@ -48,20 +48,34 @@ class Cell:
                            "configuration")
         cfg_path = repo / cfg_entry["file"]
         self.config = load_json(cfg_path)
+        self.reference_path = cfg_path.with_name(cfg_path.stem + ".ref.py")
         self.reference = load_module(
-            cfg_path.with_name(cfg_path.stem + ".ref.py"),
+            self.reference_path,
             "ref_" + cfg_path.stem.replace("-", "_").replace(".", "_"))
         self.traffic = load_json(bench_dir / "traffic"
                                  / f"{self.entry['traffic']}.json")
         self.limits = load_json(bench_dir / "limits" / f"{name}.json")
         self.peaks = load_json(bench_dir / "peaks.json")
         self.bench_dir = bench_dir
+        self.check_counts()
 
     def metrics(self, section: str) -> List[dict]:
         """The metrics of ``section`` (end_to_end | per_layer) this cell
         reports: those without a ``workloads`` key, and those naming it."""
         return [m for m in self.bench[section]
                 if self.name in m.get("workloads", [self.name])]
+
+    def check_counts(self) -> None:
+        """Each per-layer metric's reader names, in ``NEEDS``, the counts
+        it takes from the configuration's reference; one that is missing
+        stops the run at set-up, by name."""
+        for m in self.metrics("per_layer"):
+            for fn in getattr(self.reader(m["name"]), "NEEDS", ()):
+                if not callable(getattr(self.reference, fn, None)):
+                    raise AttributeError(
+                        f"{self.name} reports {m['name']}, which needs "
+                        f"{fn}() from {self.reference_path.name}; it has "
+                        "none")
 
     def reader(self, metric: str) -> ModuleType:
         return load_module(self.bench_dir / "metrics" / f"{metric}.py",

@@ -180,7 +180,11 @@ def is_container(op) -> bool:
 
 
 def is_collective(op) -> bool:
-    return bool(COLLECTIVE.match(op[0]))
+    """A collective by its name, or by its HLO category where the op row
+    carries one (``program_trace``): the compiler names a reduce-scatter
+    it fused ``fusion.N``, of category ``all-reduce-scatter fusion``."""
+    return bool(COLLECTIVE.match(op[0])
+                or (len(op) > 4 and COLLECTIVE.match(op[4])))
 
 
 def is_kernel(op, kernel: str) -> bool:

@@ -1,11 +1,13 @@
 """The one traffic generator.  A mix is a data file under ``traffic/``;
 everything drawn here comes from ``--seed`` and nothing else.
 
-Training mixes (``"kind": "train"``) give a stream of BERT masked-LM
-batches.  Serving mixes (``"kind": "serve"``) give an open-loop schedule
-of greedy requests: Poisson arrivals, with independent lognormal prompt
-and output lengths whose draw every seed shares and deals out in its own
-order, so that seeds change the order of the work and not its amount.
+Training mixes (``"kind": "train"``) give a stream of batches for the
+mix's ``objective``: BERT masked-LM (``mlm``) or next-token prediction
+(``causal_lm``).  Serving mixes (``"kind": "serve"``) give an open-loop
+schedule of greedy requests: Poisson arrivals, with independent lognormal
+prompt and output lengths whose draw every seed shares and deals out in
+its own order, so that seeds change the order of the work and not its
+amount.
 """
 from __future__ import annotations
 
@@ -16,6 +18,7 @@ from typing import Dict, Iterator, List
 import numpy as np
 
 MASK_CHANCE, RANDOM_CHANCE = 0.8, 0.1  # BERT: 80% [MASK], 10% random, 10% kept
+IGNORE = -1  # the label of a position that is not predicted
 
 
 def seed_words(seed: int) -> np.ndarray:
@@ -28,13 +31,24 @@ def rng(seed: int, stream: int) -> np.random.Generator:
 
 
 # ---------------------------------------------------------------------------
-# training: masked-LM batches
+# training: masked-LM or next-token batches
 # ---------------------------------------------------------------------------
 
+def objective(traffic: dict) -> str:
+    obj = traffic["objective"]
+    if obj not in TRAIN_BATCHES:
+        raise ValueError(f"unknown training objective {obj!r} (known: "
+                         f"{sorted(TRAIN_BATCHES)})")
+    return obj
+
+
 def predictions_per_row(traffic: dict) -> int:
-    """BERT's count: round(seq * mask_prob), at least 1, at most
-    ``max_predictions``."""
+    """Predicted positions of a row.  MLM: BERT's count, round(seq *
+    mask_prob), at least 1, at most ``max_predictions``.  Next-token: every
+    position but the last."""
     s = traffic["seq_len"]
+    if objective(traffic) == "causal_lm":
+        return s - 1
     return min(traffic["max_predictions"],
                max(1, int(round(s * traffic["mask_prob"]))))
 
@@ -55,13 +69,13 @@ class ZipfTokens:
                 + self.first_id).astype(np.int32)
 
 
-def mlm_batches(traffic: dict, cfg: dict, seed: int,
-                rows: int = 0) -> Iterator[Dict[str, np.ndarray]]:
-    """Endless masked-LM batches of ``global_batch`` rows (or ``rows``).
+def mlm_batches(traffic: dict, cfg: dict,
+                seed: int) -> Iterator[Dict[str, np.ndarray]]:
+    """Endless masked-LM batches of ``global_batch`` rows.
 
     Each row masks the same number of positions, chosen uniformly; every
     row of every batch is drawn afresh, so no two rows repeat."""
-    b = rows or traffic["global_batch"]
+    b = traffic["global_batch"]
     s = traffic["seq_len"]
     n_pred = predictions_per_row(traffic)
     vocab = cfg["vocab_size"]
@@ -71,7 +85,7 @@ def mlm_batches(traffic: dict, cfg: dict, seed: int,
         tokens = toks.draw(g, (b, s))
         pos = np.argsort(g.random((b, s)), axis=1)[:, :n_pred]
         rows_ix = np.arange(b)[:, None]
-        labels = np.full((b, s), -1, np.int32)
+        labels = np.full((b, s), IGNORE, np.int32)
         labels[rows_ix, pos] = tokens[rows_ix, pos]
         u = g.random((b, n_pred))
         picked = tokens[rows_ix, pos]
@@ -83,8 +97,34 @@ def mlm_batches(traffic: dict, cfg: dict, seed: int,
         yield {"tokens": tokens, "labels": labels}
 
 
+def causal_lm_batches(traffic: dict, cfg: dict,
+                      seed: int) -> Iterator[Dict[str, np.ndarray]]:
+    """Endless next-token batches of ``global_batch`` rows: each row an
+    unbroken stream of ``seq_len`` tokens drawn afresh, ``labels[t] =
+    tokens[t + 1]`` and the last position not predicted."""
+    b = traffic["global_batch"]
+    s = traffic["seq_len"]
+    toks = ZipfTokens(cfg["vocab_size"], traffic["zipf_a"],
+                      traffic["first_token_id"])
+    g = rng(seed, 1)
+    while True:
+        tokens = toks.draw(g, (b, s))
+        labels = np.full((b, s), IGNORE, np.int32)
+        labels[:, :-1] = tokens[:, 1:]
+        yield {"tokens": tokens, "labels": labels}
+
+
+TRAIN_BATCHES = {"mlm": mlm_batches, "causal_lm": causal_lm_batches}
+
+
+def train_batches(traffic: dict, cfg: dict,
+                  seed: int) -> Iterator[Dict[str, np.ndarray]]:
+    """The mix's endless stream of training batches."""
+    return TRAIN_BATCHES[objective(traffic)](traffic, cfg, seed)
+
+
 def first_batches(traffic: dict, cfg: dict, seed: int, n: int) -> List[dict]:
-    it = mlm_batches(traffic, cfg, seed)
+    it = train_batches(traffic, cfg, seed)
     return [next(it) for _ in range(n)]
 
 

@@ -1,6 +1,6 @@
-"""Training cells: bert-large MLM under fused LAMB, through the jitted step
-that ``Trainer`` builds, fed by ``DataPipeline`` prefetching the traffic
-generator's batches.
+"""Training cells: a configuration under LAMB, through the jitted step that
+``Trainer`` builds, fed by ``DataPipeline`` prefetching the traffic
+generator's batches for the mix's objective (masked-LM or next-token).
 
 Set-up builds the trainer and its state (weights drawn on the device from
 the seed in one jitted call), then drives that same step through its
@@ -8,8 +8,9 @@ first steps on the window's own feed; their losses, the first gradient as
 LAMB's first moment holds it, and the weights' change after three steps
 are kept for the check.  A few more steps warm up, then the window runs
 for ``--seconds``.  Once it has closed and the program's state is freed,
-the plain reference (``configs/<config>.ref.py``) follows the same first
-steps in float32 and the readings are compared.
+the plain reference (``configs/<config>.ref.py``, under the shared
+``chipbench/ref_lamb.py``) follows the same first steps in float32 and the
+readings are compared.
 """
 from __future__ import annotations
 
@@ -23,8 +24,9 @@ import numpy as np
 
 from chipbench import BENCH_DIR
 from chipbench import common as cm
+from chipbench import ref_lamb
 from chipbench import trace as tr
-from chipbench.traffic import first_batches, mlm_batches, predictions_per_row
+from chipbench.traffic import first_batches, predictions_per_row, train_batches
 
 CHECK_STEPS = 3
 
@@ -69,6 +71,21 @@ def readings(prog: dict, ref: dict) -> dict:
     }
 
 
+def in_sharding(step: Callable, ctx) -> Callable:
+    """The step traced and run under the trainer's sharding context, as
+    ``Trainer.fit`` runs it: without the context the model's activation
+    sharding rules do not hold, and on a mesh XLA keeps the whole batch's
+    activations on every chip (bert-large at batch 128 on four chips then
+    asks 25 GB a chip)."""
+    from repro.sharding.context import use_sharding
+
+    def run(state, batch):
+        with use_sharding(ctx):
+            return step(state, batch)
+
+    return run
+
+
 class Program:
     """The system under test, built once: model, trainer, compiled step."""
 
@@ -99,6 +116,8 @@ class Program:
         # the program's private seams (PERF.md, Open questions): the jitted
         # step, the state's shapes and placement, and the batch placement
         self.step = cm.seam(self.trainer, "_step_fn")
+        if self.mesh is not None:
+            self.step = in_sharding(self.step, self.trainer.shard_ctx)
         if step_hook is not None:
             self.step = step_hook(self.step)
         self.place = cm.seam(self.trainer, "_place_batch")
@@ -116,9 +135,12 @@ class Program:
                 params=params, opt_state=jax.tree.map(zeros, abstract.opt_state),
                 step=zeros(abstract.step), skipped=zeros(abstract.skipped))
 
+        def slice_norms(tree):
+            return ref_lamb.slice_norms(tree, ref.slice_axes)
+
         def change(params, kd):
             w0 = ref.init_params(cfg, jax.random.wrap_key_data(kd))
-            return ref.slice_norms(jax.tree.map(
+            return slice_norms(jax.tree.map(
                 lambda a, b: a.astype(jnp.float32) - b, params, w0))
 
         sharding = cm.seam(self.trainer, "_state_sharding")
@@ -126,7 +148,7 @@ class Program:
             init_state, **({} if sharding is None
                            else {"out_shardings": sharding}))
         self.change_norms = jax.jit(change)
-        self.moment_norms = jax.jit(ref.slice_norms)
+        self.moment_norms = jax.jit(slice_norms)
 
     def pipeline(self, seed: int):
         from repro.data import DataPipeline
@@ -135,7 +157,7 @@ class Program:
         pipe = DataPipeline(self.mc, t["global_batch"], t["seq_len"],
                             mesh=self.mesh, prefetch=t["prefetch"])
         cm.seam(pipe, "_it")
-        pipe._it = mlm_batches(t, self.cell.config, seed)
+        pipe._it = train_batches(t, self.cell.config, seed)
         return pipe
 
     def first_steps(self, seed: int):
@@ -174,10 +196,10 @@ def reference_readings(cell, seed: int, mm=None, rows_from: int = 0) -> dict:
                for b in first_batches(t, cell.config, seed, CHECK_STEPS)]
     kd = cm.key_data(seed)
     with jax.threefry_partitionable(True):
-        return ref.lamb_steps(cell.config, t["optimizer"],
-                              jax.random.wrap_key_data(kd), batches,
-                              t["reference_rows"],
-                              **({} if mm is None else {"mm": mm}))
+        return ref_lamb.lamb_steps(ref, cell.config, t["optimizer"],
+                                   jax.random.wrap_key_data(kd), batches,
+                                   t["reference_rows"],
+                                   ref.highest if mm is None else mm)
 
 
 def counters_for(cell, program: Program, tokens_per_s: float,
@@ -191,7 +213,8 @@ def counters_for(cell, program: Program, tokens_per_s: float,
         "n_params": sum(int(np.prod(x.shape)) for x in jax.tree.leaves(
             program.abstract.params)),
         "heads": c["num_attention_heads"],
-        "head_dim": c["hidden_size"] // c["num_attention_heads"],
+        "head_dim": c.get("head_dim",
+                          c["hidden_size"] // c["num_attention_heads"]),
     }
 
 

@@ -1,11 +1,12 @@
 """Plain reference for bert-large MLM training under LAMB.
 
 Straightforward ``jax.numpy`` in float32 with every matrix product at
-``Precision.HIGHEST``: the encoder forward pass, the masked-LM loss, its
-gradient by ``jax.grad``, and LAMB (You et al., ICLR 2020, Algorithm 2)
-with global-norm clipping and per-layer trust ratios.  It follows the
-configuration in ``bert-large.json`` as it is run, departures included,
-and imports nothing of the program under test.
+``Precision.HIGHEST``: the encoder forward pass and the masked-LM loss,
+whose gradient ``jax.grad`` takes; LAMB itself is the shared
+``chipbench/ref_lamb.py``, told here which leaves are decayed and how
+they are sliced.  It follows the configuration in ``bert-large.json`` as
+it is run, departures included, and imports nothing of the program under
+test.  ``train_flops_per_token`` is the count ``train_mfu`` divides by.
 
 ``mm`` is the one matrix-product function every einsum goes through: the
 reference passes ``highest``; the precision control passes a function that
@@ -62,9 +63,10 @@ def _is_spec(x):
     return isinstance(x, tuple) and len(x) == 2 and isinstance(x[1], str)
 
 
-def init_params(cfg, key):
-    """Float32 weights from ``key``: BERT's truncated normal at
-    ``initializer_range`` for every matrix, LayerNorm scale 1, bias 0."""
+def init_params(cfg, key, dtype=jnp.float32):
+    """Weights from ``key``: BERT's truncated normal at
+    ``initializer_range`` for every matrix, LayerNorm scale 1, bias 0;
+    drawn in float32 and rounded to ``dtype``."""
     std = cfg["initializer_range"]
     flat, tree = jax.tree_util.tree_flatten(param_specs(cfg), is_leaf=_is_spec)
     out = []
@@ -76,13 +78,19 @@ def init_params(cfg, key):
         else:
             out.append(std * jax.random.truncated_normal(
                 jax.random.fold_in(key, i), -2.0, 2.0, shape, jnp.float32))
-    return jax.tree_util.tree_unflatten(tree, out)
+    return jax.tree_util.tree_unflatten(
+        tree, [x.astype(dtype) for x in out])
 
 
 def decayed(path: str) -> bool:
     """Weight decay and the trust ratio apply to every matrix and the
     embedding, not to LayerNorm scales and biases (LAMB's reference code)."""
     return not (path.endswith("scale") or path.endswith("bias"))
+
+
+def slice_axes(path: str) -> int:
+    """Leaves under "blocks" take one trust ratio per layer."""
+    return 1 if path.startswith("blocks/") else 0
 
 
 # ---------------------------------------------------------------------------
@@ -137,9 +145,9 @@ def encoder(params, tokens, cfg, mm=highest):
     return layer_norm(x, params["final_norm"], eps)
 
 
-def mlm_nll_sum(params, tokens, labels, cfg, mm=highest):
-    """(sum of the negative log-likelihoods at labels >= 0, their count),
-    the MLM head being the tied embedding."""
+def nll_sum(params, tokens, labels, cfg, mm=highest):
+    """The masked-LM loss: (sum of the negative log-likelihoods at labels
+    >= 0, their count), the MLM head being the tied embedding."""
     hid = encoder(params, tokens, cfg, mm)
     logits = mm("bsd,vd->bsv", hid, params["embed"])
     mask = labels >= 0
@@ -150,93 +158,18 @@ def mlm_nll_sum(params, tokens, labels, cfg, mm=highest):
 
 
 # ---------------------------------------------------------------------------
-# LAMB
+# operation counts
 # ---------------------------------------------------------------------------
 
-def slice_norms(tree):
-    """{path: per-layer-slice L2 norms} (one value for unstacked leaves)."""
-    out = {}
-    for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        name = "/".join(str(k.key) for k in path)
-        x = jnp.asarray(x, jnp.float32)
-        axes = tuple(range(1, x.ndim)) if name.startswith("blocks/") else None
-        out[name] = jnp.atleast_1d(jnp.sqrt(jnp.sum(x * x, axis=axes)))
-    return out
-
-
-def lr_at(job, count):
-    """The job's schedule: linear warmup, then linear decay to 0."""
-    base, total, warm = (job["learning_rate"], job["total_steps"],
-                         job["warmup_steps"])
-    if warm and count < warm:
-        return base * count / warm
-    frac = min(max((count - warm) / max(total - warm, 1), 0.0), 1.0)
-    return base * (1.0 - frac)
-
-
-def _lamb(params, grads, m, v, t, lr, job):
-    b1, b2, eps, wd = job["b1"], job["b2"], job["eps"], job["weight_decay"]
-    gsq = sum(jnp.sum(g * g) for g in jax.tree.leaves(grads))
-    clip = jnp.minimum(1.0, job["grad_clip_norm"] / (jnp.sqrt(gsq) + 1e-12))
-
-    def one(path, w, g, m, v):
-        name = "/".join(str(k.key) for k in path)
-        g = g * clip
-        m = b1 * m + (1 - b1) * g
-        v = b2 * v + (1 - b2) * g * g
-        u = (m / (1 - b1 ** t)) / (jnp.sqrt(v / (1 - b2 ** t)) + eps)
-        if decayed(name):
-            u = u + wd * w
-            axes = tuple(range(1, w.ndim)) if name.startswith("blocks/") else None
-            wn = jnp.sqrt(jnp.sum(w * w, axis=axes, keepdims=axes is not None))
-            un = jnp.sqrt(jnp.sum(u * u, axis=axes, keepdims=axes is not None))
-            ratio = jnp.where((wn > 0) & (un > 0), wn / un, 1.0)
-        else:
-            ratio = 1.0
-        return w - lr * ratio * u, m, v
-
-    out = jax.tree_util.tree_map_with_path(one, params, grads, m, v)
-    pick = lambda i: jax.tree.map(lambda o: o[i], out,  # noqa: E731
-                                  is_leaf=lambda o: isinstance(o, tuple))
-    return pick(0), pick(1), pick(2)
-
-
-def lamb_steps(cfg, job, key, batches, rows, mm=highest):
-    """Run LAMB from the weights of ``key`` over ``batches`` (a list of
-    (tokens, labels) host arrays), ``rows`` rows at a time.
-
-    Returns the per-step losses, the per-slice norms of the first step's
-    clipped gradient, and the per-slice norms of the weights' change after
-    the last step, all as numpy.
-    """
-    params = jax.jit(lambda k: init_params(cfg, k))(key)
-    w0 = params
-    grad_fn = jax.jit(jax.value_and_grad(
-        lambda p, t, l: mlm_nll_sum(p, t, l, cfg, mm), has_aux=True))
-    acc = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b))
-    update = jax.jit(lambda p, g, m, v, t, lr: _lamb(p, g, m, v, t, lr, job))
-    zeros = jax.jit(lambda p: jax.tree.map(jnp.zeros_like, p))
-    m, v = zeros(params), zeros(params)
-    losses, grad_norms = [], None
-    for t, (tokens, labels) in enumerate(batches, start=1):
-        total = count = grads = None
-        for r in range(0, tokens.shape[0], rows):
-            (s, c), g = grad_fn(params, jnp.asarray(tokens[r:r + rows]),
-                                jnp.asarray(labels[r:r + rows]))
-            grads = g if grads is None else acc(grads, g)
-            total = s if total is None else total + s
-            count = c if count is None else count + c
-        count = jnp.maximum(count, 1).astype(jnp.float32)
-        grads = jax.tree.map(lambda g: g / count, grads)
-        losses.append(float(total / count))
-        if t == 1:
-            gsq = sum(float(jnp.sum(g * g)) for g in jax.tree.leaves(grads))
-            clip = min(1.0, job["grad_clip_norm"] / (math.sqrt(gsq) + 1e-12))
-            grad_norms = {k: np.asarray(n) * clip
-                          for k, n in slice_norms(grads).items()}
-        params, m, v = update(params, grads, m, v, float(t),
-                              lr_at(job, t - 1))
-    change = jax.tree.map(jnp.subtract, params, w0)
-    return {"losses": losses, "grad_norms": grad_norms,
-            "change_norms": {k: np.asarray(n)
-                             for k, n in slice_norms(change).items()}}
+def train_flops_per_token(cfg, seq, n_pred):
+    """Model operations per token of one MLM training step: forward and
+    backward (3 matrix products per forward one) of the encoder's weight
+    products, attention's score and value products over ``seq`` keys, and
+    the tied MLM head on the ``n_pred`` predicted rows of each sequence.
+    Recomputation is not counted; a multiply-add is two operations."""
+    d, n, ff, v = (cfg["hidden_size"], cfg["num_hidden_layers"],
+                   cfg["intermediate_size"], cfg["vocab_size"])
+    weights = n * (4 * d * d + 2 * d * ff)
+    attention = n * 2 * seq * d
+    head = d * v * n_pred / seq
+    return 6.0 * (weights + attention + head)

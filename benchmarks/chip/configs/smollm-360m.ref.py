@@ -8,8 +8,12 @@ nothing of the program under test.  ``mm`` is the one matrix-product
 function every einsum goes through (the precision control swaps it).
 
 Weights are drawn here from the seed for the program and the reference
-alike, and served in bfloat16: the reference takes the same bfloat16
-values and computes with them in float32.
+alike.  Served, they are bfloat16: the reference takes the same bfloat16
+values and computes with them in float32.  Trained, they are float32
+masters, and ``nll_sum`` (the next-token loss) with ``decayed`` and
+``slice_axes`` give the shared LAMB reference (``chipbench/ref_lamb.py``)
+a decoder.  The operation counts at the end are what ``serve_mfu`` and
+``train_mfu`` divide by.
 """
 from __future__ import annotations
 
@@ -54,7 +58,7 @@ def _is_spec(x):
     return isinstance(x, tuple) and len(x) == 2 and isinstance(x[1], str)
 
 
-def init_params(cfg, key, dtype=jnp.bfloat16):
+def init_params(cfg, key, dtype=jnp.float32):
     """Weights from ``key``: normal at ``initializer_range`` for every
     matrix (Llama's init), RMSNorm scale 1; rounded to ``dtype``."""
     std = cfg["initializer_range"]
@@ -68,6 +72,17 @@ def init_params(cfg, key, dtype=jnp.bfloat16):
                                         jnp.float32)
         out.append(x.astype(dtype))
     return jax.tree_util.tree_unflatten(tree, out)
+
+
+def decayed(path: str) -> bool:
+    """Weight decay and the trust ratio apply to every matrix and the
+    embedding, not to RMSNorm scales."""
+    return not path.endswith("scale")
+
+
+def slice_axes(path: str) -> int:
+    """Leaves under "blocks" take one trust ratio per layer."""
+    return 1 if path.startswith("blocks/") else 0
 
 
 def rms_norm(x, scale, eps):
@@ -117,3 +132,58 @@ def logits(params, tokens, cfg, mm=highest):
     x, _ = jax.lax.scan(layer, x, p32["blocks"])
     x = rms_norm(x, p32["final_norm"]["scale"], eps)
     return mm("bsd,vd->bsv", x, p32["embed"])
+
+
+def nll_sum(params, tokens, labels, cfg, mm=highest):
+    """The next-token loss: (sum of the negative log-likelihoods at labels
+    >= 0, their count), ``labels[t]`` being the token that follows
+    position t."""
+    lg = logits(params, tokens, cfg, mm)
+    mask = labels >= 0
+    lse = jax.nn.logsumexp(lg, axis=-1)
+    ll = jnp.take_along_axis(lg, jnp.maximum(labels, 0)[..., None],
+                             axis=-1)[..., 0]
+    return jnp.sum(jnp.where(mask, lse - ll, 0.0)), jnp.sum(mask)
+
+
+# ---------------------------------------------------------------------------
+# operation counts (a multiply-add is two operations)
+# ---------------------------------------------------------------------------
+
+def decoder_layer_macs(cfg):
+    """Weight multiply-adds of one token through every layer."""
+    d, n, h, ff = (cfg["hidden_size"], cfg["num_hidden_layers"],
+                   cfg["num_attention_heads"], cfg["intermediate_size"])
+    kv = cfg.get("num_key_value_heads", h)
+    dh = d // h
+    return n * (2 * d * h * dh + 2 * d * kv * dh + 3 * d * ff)
+
+
+def decode_token_flops(cfg, ctx):
+    """Operations of one decoded token that attends to ``ctx`` positions,
+    its logits included."""
+    d, n = cfg["hidden_size"], cfg["num_hidden_layers"]
+    attention = n * 2 * d * ctx          # scores and values, all heads
+    return 2.0 * (decoder_layer_macs(cfg) + attention
+                  + d * cfg["vocab_size"])
+
+
+def prefill_flops(cfg, s):
+    """Operations of a causal prefill of ``s`` tokens with the logits of
+    its last position only."""
+    d, n = cfg["hidden_size"], cfg["num_hidden_layers"]
+    attention = n * 2 * d * s * (s + 1) / 2
+    return 2.0 * (s * decoder_layer_macs(cfg) + attention
+                  + d * cfg["vocab_size"])
+
+
+def train_flops_per_token(cfg, seq, n_pred):
+    """Model operations per token of one next-token training step:
+    forward and backward (3 matrix products per forward one) of the
+    layers' weight products, causal attention's score and value products
+    over (seq + 1) / 2 keys on average, and the tied head on the ``n_pred``
+    predicted positions of each sequence.  Recomputation is not counted."""
+    d, n, v = cfg["hidden_size"], cfg["num_hidden_layers"], cfg["vocab_size"]
+    attention = n * d * (seq + 1)
+    head = d * v * n_pred / seq
+    return 6.0 * (decoder_layer_macs(cfg) + attention + head)

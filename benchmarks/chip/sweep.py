@@ -47,7 +47,7 @@ def main() -> None:
         cell.traffic["rate_per_s"] = float(rate)
         prog.engine.scheduler.max_prefills_per_step = int(cap)
         rec = S.run_window(prog, args.seed, args.seconds)
-        st = S.window_stats(rec, args.seconds, cell.config)
+        st = S.window_stats(rec, args.seconds)
         q = lambda xs, p: 1e3 * float(np.percentile(xs, p)) if xs else None  # noqa: E731
         print(json.dumps({
             "rate_per_s": float(rate), "max_prefills_per_step": int(cap),

@@ -170,9 +170,9 @@ def test_read_takes_scopes_from_op_metadata_and_args_from_spans(tmp_path):
     assert t["spans"] == [["serve.admit", 1005, 50, {"rid": 4}]]
     dev = t["devices"]["0"]
     assert dev["ops"] == [
-        ["fusion.1", 1000, 30, "jit(step_fn)/jvp(model)/dot"],
-        ["fusion.2", 1030, 40, "jit(step_fn)/transpose(jvp(model))/dot"],
-        ["copy.3", 1070, 20, ""]]
+        ["fusion.1", 1000, 30, "jit(step_fn)/jvp(model)/dot", "loop fusion"],
+        ["fusion.2", 1030, 40, "jit(step_fn)/transpose(jvp(model))/dot", ""],
+        ["copy.3", 1070, 20, "", "copy"]]
     assert dev["modules"] == [["jit_step_fn(7)", 1000, 90]]
     assert pt.step_parts_ms(t) == pytest.approx({
         "forward": 30e-6, "backward": 40e-6, "optimizer": 0.0, "cast": 0.0,
@@ -215,7 +215,10 @@ def test_idle_covered_by_spans_and_prefills_inside_admissions():
 # two steps of seq 128 (ops' name stacks cut to their first three
 # components, which place every op) and three serving turns
 RECORDED = {"train": BENCH_DIR / "testdata" / "train128.program.json.gz",
-            "serve": BENCH_DIR / "testdata" / "serve.program.json.gz"}
+            "serve": BENCH_DIR / "testdata" / "serve.program.json.gz",
+            # one step of bert-large.train.seq128.data4 on two of its four
+            # chips (PR 16), each op with its HLO category
+            "train4": BENCH_DIR / "testdata" / "train128data4.program.json.gz"}
 
 
 def _recorded(kind):
@@ -243,3 +246,25 @@ def test_recorded_serve_extract_shares_the_device_clock():
     assert _idle_covered_share(t) >= 95.0
     for m in SERVE_METRICS:
         assert _read("smollm-360m.serve.steady", m, t) > 0
+
+
+def test_recorded_four_chip_extract_finds_the_fused_reduce_scatter():
+    t = _recorded("train4")
+    ops = t["devices"]["0"]["ops"]
+    # the embedding's gradient reduce-scatter: a fusion, known by category
+    fused = [op for op in ops
+             if tr.is_collective(op) and not tr.COLLECTIVE.match(op[0])]
+    assert [op[4] for op in fused] == ["all-reduce-scatter fusion"]
+    # the weight gathers: rings of collective permutes inside the loop
+    assert any(op[0].startswith("collective-permute-done") for op in ops)
+    exposed = _read("bert-large.train.seq128.data4",
+                    "train_collective_exposed_ms", t)
+    by_name = tr.subtract(
+        tr.op_intervals(ops, lambda op: bool(tr.COLLECTIVE.match(op[0]))),
+        tr.op_intervals(ops, lambda op: not tr.is_collective(op)
+                        and not tr.is_container(op))) / 1e6
+    assert by_name < exposed < 20.0
+    parts = pt.step_parts_ms(t)
+    assert exposed < parts["busy"]
+    assert parts["forward"] + parts["backward"] + parts["optimizer"] \
+        + parts["cast"] <= parts["busy"] + 1e-6

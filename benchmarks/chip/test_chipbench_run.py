@@ -56,6 +56,16 @@ def test_train_result_line_has_the_contract_keys(tree, capsys):
     assert line["device"]["count"] == 1 and line["attempted"] > 0
 
 
+def test_lm_train_result_line_has_the_contract_keys(tree, capsys):
+    """A decoder trained next-token, added by files alone, runs end to end
+    and is correct."""
+    c, line = run_cell(tree, tiny.LM_TRAIN, capsys=capsys)
+    assert list(line) == CONTRACT_KEYS
+    assert line["correct"] is True, line["checks"]
+    assert set(line["metrics"]) == {"train_tokens_per_s", "setup_s"}
+    assert c.traffic["objective"] == "causal_lm"
+
+
 def test_serve_result_line_has_the_contract_keys(tree, capsys):
     c, line = run_cell(tree, tiny.SERVE, capsys=capsys)
     assert list(line) == CONTRACT_KEYS
@@ -88,10 +98,20 @@ def _half_batch(step):
     return broken
 
 
-@pytest.mark.parametrize("hook", [_unchanged, _half_batch],
-                         ids=["state_unchanged", "half_batch"])
-def test_train_fault_is_not_correct(tree, hook):
-    _, res = run_cell(tree, tiny.TRAIN, step_hook=hook)
+def _labels_not_shifted(step):
+    def broken(state, batch):
+        return step(state, dict(batch, labels=batch["tokens"]))
+    return broken
+
+
+@pytest.mark.parametrize("name,hook", [
+    (tiny.TRAIN, _unchanged), (tiny.TRAIN, _half_batch),
+    (tiny.LM_TRAIN, _unchanged), (tiny.LM_TRAIN, _half_batch),
+    (tiny.LM_TRAIN, _labels_not_shifted)],
+    ids=["state_unchanged", "half_batch", "lm-state_unchanged",
+         "lm-half_batch", "lm-labels_not_shifted"])
+def test_train_fault_is_not_correct(tree, name, hook):
+    _, res = run_cell(tree, name, step_hook=hook)
     assert res["correct"] is False, res["checks"]
 
 
@@ -114,10 +134,11 @@ def test_serve_altered_token_is_not_correct(tree):
     assert res["correct"] is False, res["checks"]
 
 
-def test_train_precision_control_fails_the_limits(tree):
+@pytest.mark.parametrize("name", [tiny.TRAIN, tiny.LM_TRAIN])
+def test_train_precision_control_fails_the_limits(tree, name):
     from chipbench import train as T
 
-    c = cell(tree, tiny.TRAIN)
+    c = cell(tree, name)
     seed = 7
     ref = T.reference_readings(c, seed)
     control = T.readings(T.reference_readings(c, seed, mm=fp8_matmul), ref)
@@ -191,3 +212,16 @@ def test_four_chip_run_and_its_missing_exchange(tmp_path):
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     assert out["sound"][:2] == [True, 4], out
     assert out["no_exchange"][0] is False, out
+
+
+def test_a_mesh_step_runs_under_the_trainers_sharding_context():
+    """On a mesh the step is traced where ``Trainer.fit`` traces it: under
+    the trainer's sharding context, so the model's activation rules hold."""
+    from jax.sharding import Mesh
+
+    from chipbench.train import in_sharding
+    from repro.sharding.context import ShardCtx, current
+
+    ctx = ShardCtx(Mesh(np.array(jax.devices()[:1]), ("data",)))
+    seen = in_sharding(lambda state, batch: current(), ctx)(None, None)
+    assert seen is ctx and current() is None

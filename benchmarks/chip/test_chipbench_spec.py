@@ -107,3 +107,109 @@ def test_new_cell_config_and_metric_need_only_new_files(tmp_path):
     assert cell.reader("tiny_probe").read(None) == 42.0
     assert Cell(tiny.SERVE, repo=tmp_path, bench_dir=bench).config[
         "name"] == "tiny-lm"
+    # a decoder trained next-token, under the decoder's reference
+    lm = Cell(tiny.LM_TRAIN, repo=tmp_path, bench_dir=bench)
+    assert lm.config["program"]["param_dtype"] == "float32"
+    assert lm.traffic["objective"] == "causal_lm"
+    assert callable(lm.reference.nll_sum)
+    assert callable(lm.reference.train_flops_per_token)
+    # an architecture the program builds from its program block alone
+    moe = json.loads((bench / "configs" / f"{tiny.MOE}.json").read_text())
+    assert moe["program"]["use_mla"] and moe["program"]["n_experts"] == 8
+
+
+# what program_config built for the cells before it took the program block
+# by field name, field for field; every other field is ModelConfig's default
+BERT_BEFORE = dict(
+    name="bert-large", family="dense", n_layers=24, d_model=1024, n_heads=16,
+    n_kv_heads=16, d_ff=4096, vocab_size=30522, head_dim=64,
+    rope_theta=10000.0, causal=False, norm_type="layernorm", act_fn="gelu",
+    gated_mlp=False, use_rope=True, tie_embeddings=True, mask_ratio=0.15,
+    use_flash_kernel=True, use_fused_ce_head=True, param_dtype="float32",
+    activation_dtype="bfloat16")
+SMOL_BEFORE = dict(
+    name="smollm-360m", family="dense", n_layers=32, d_model=960, n_heads=15,
+    n_kv_heads=5, d_ff=2560, vocab_size=49152, head_dim=64,
+    rope_theta=10000.0, causal=True, norm_type="rmsnorm", act_fn="silu",
+    gated_mlp=True, use_rope=True, tie_embeddings=True, mask_ratio=0.0,
+    use_flash_kernel=False, use_fused_ce_head=False,
+    param_dtype="bfloat16", activation_dtype="bfloat16",
+    mlm_max_predictions=None)
+
+
+@pytest.mark.parametrize("workload,before", [
+    ("bert-large.train.seq128", dict(BERT_BEFORE, mlm_max_predictions=20)),
+    ("bert-large.train.seq512", dict(BERT_BEFORE, mlm_max_predictions=80)),
+    ("bert-large.train.seq128.data4",
+     dict(BERT_BEFORE, mlm_max_predictions=20)),
+    ("smollm-360m.serve.steady", SMOL_BEFORE)])
+def test_program_config_builds_what_it_built_before(workload, before):
+    import dataclasses
+
+    from chipbench import common as cm
+    from repro.configs.base import ModelConfig
+
+    defaults = {f.name: f.default for f in dataclasses.fields(ModelConfig)
+                if f.default is not dataclasses.MISSING}
+    cell = Cell(workload)
+    got = dataclasses.asdict(cm.program_config(cell.config, cell.traffic))
+    assert got == {**defaults, **before}
+
+
+def test_an_unknown_program_key_is_named():
+    from chipbench import common as cm
+
+    cell = Cell("smollm-360m.serve.steady")
+    cfg = dict(cell.config, program=dict(cell.config["program"],
+                                          n_expert=8))
+    with pytest.raises(KeyError, match="n_expert"):
+        cm.program_config(cfg, cell.traffic)
+
+
+def test_moe_and_latent_attention_come_from_the_program_block(tmp_path):
+    from chipbench import common as cm
+    from repro.models import build_model
+
+    bench = tiny.build(tmp_path)
+    cfg = json.loads((bench / "configs" / f"{tiny.MOE}.json").read_text())
+    lm = json.loads((bench / "traffic/tiny.lm.json").read_text())
+    mc = cm.program_config(cfg, lm)
+    assert (mc.family, mc.n_experts, mc.n_experts_per_tok,
+            mc.n_shared_experts, mc.moe_d_ff, mc.n_dense_layers,
+            mc.use_mla) == ("moe", 8, 2, 1, 32, 1, True)
+    params = build_model(mc).abstract_params()
+    assert "dense_blocks" in params
+    # expert leaves stacked as (layers, experts, d, f)
+    assert params["blocks"]["moe"]["wi"].shape == (2, 8, 64, 32)
+    assert params["blocks"]["moe"]["wo"].shape == (2, 8, 32, 64)
+    # latent attention: keys and values through the kv_lora_rank latent
+    assert params["blocks"]["attn"]["wkv_a"].shape == (2, 64, 16 + 8)
+
+
+def test_a_metric_whose_count_the_reference_lacks_stops_set_up(tmp_path):
+    bench = tiny.build(tmp_path)
+    ref = bench / "configs" / "tiny-lm-f32.ref.py"
+    text = ref.read_text().replace("def train_flops_per_token(",
+                                   "def _gone(")
+    ref.write_text(text)
+    with pytest.raises(AttributeError,
+                       match=r"train_mfu, which needs train_flops_per_token"):
+        Cell(tiny.LM_TRAIN, repo=tmp_path, bench_dir=bench)
+
+
+def test_shared_lamb_reference_slices_by_leading_axes():
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chipbench import ref_lamb
+
+    tree = {"blocks": {"moe": {"wi": jnp.ones((3, 8, 4, 5))},
+                       "attn": {"wq": jnp.ones((3, 4, 2))}},
+            "embed": jnp.ones((6, 4))}
+    axes = {"blocks/moe/wi": 2, "blocks/attn/wq": 1, "embed": 0}
+    norms = ref_lamb.slice_norms(tree, axes.__getitem__)
+    assert norms["blocks/moe/wi"].shape == (3, 8)
+    assert np.allclose(norms["blocks/moe/wi"], np.sqrt(20))
+    assert norms["blocks/attn/wq"].shape == (3,)
+    assert norms["embed"].shape == (1,)
+    assert np.allclose(norms["embed"], np.sqrt(24))

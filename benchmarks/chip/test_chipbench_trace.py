@@ -128,3 +128,46 @@ def test_recorded_serve_trace_gives_step_times():
     names = {m[0].split("(")[0] for d in t["devices"].values()
              for m in d["modules"]}
     assert "jit_step" in names
+
+
+def _with_steps(t, n_steps, step_ns=10_000_000):
+    for d in t["devices"].values():
+        d["modules"] = [["jit_step_fn", i * step_ns, step_ns]
+                        for i in range(n_steps)]
+    return t
+
+
+def test_collective_exposed_ms_reads_only_what_no_op_hides():
+    from chipbench.spec import Cell
+
+    cell = Cell("bert-large.train.seq128.data4")
+    reader = cell.reader("train_collective_exposed_ms")
+
+    def read(ops_by_dev):
+        ctx = _Ctx(cell, _trace({}), {})
+        ctx.program_trace = _with_steps(_trace(ops_by_dev, (0, 20_000_000)),
+                                        2)
+        return reader.read(ctx)
+
+    # a collective wholly under a fusion reads 0
+    assert read({"0": [("all-gather-start.1", 0, 4e6, "", "all-gather"),
+                       ("fusion.2", 0, 4e6, "", "loop fusion")]}) == 0.0
+    # one half hidden reads half of it: 2 ms over 2 steps is 1 ms a step;
+    # a reduce-scatter the compiler fused is known by its category
+    assert read({"0": [("fusion.3", 0, 4e6, "", "all-reduce-scatter fusion"),
+                       ("fusion.4", 2e6, 2e6, "", "loop fusion")]}) == \
+        pytest.approx(1.0)
+    # the async parts count, a loop's container is no compute; mean of chips
+    assert read({
+        "0": [("while.1", 0, 2e7, "", "while"),
+              ("collective-permute-done.5", 0, 4e6, "",
+               "collective-permute-done")],
+        "1": [("collective-permute-start.2", 0, 4e6, "",
+               "collective-permute-start"),
+              ("fusion.6", 0, 4e6, "", "convolution fusion")]}) == \
+        pytest.approx(1.0)
+    # no collective at all (one chip), or no trace: the metric is silent
+    assert read({"0": [("fusion.7", 0, 4e6, "", "loop fusion")]}) is None
+    ctx = _Ctx(cell, _trace({}), {})
+    ctx.program_trace = None
+    assert reader.read(ctx) is None

@@ -7,6 +7,7 @@ import json
 from collections import Counter
 
 import numpy as np
+import pytest
 
 from chipbench import BENCH_DIR, REPO
 from chipbench import traffic as tf
@@ -99,3 +100,35 @@ def test_mlm_batches_are_deterministic_and_mask_bert_style():
     # no two rows repeat, within or across batches
     rows = {r.tobytes() for x in a for r in x["tokens"]}
     assert len(rows) == 64
+
+
+def test_causal_lm_batches_are_deterministic_and_shift_by_one():
+    lm = {k: v for k, v in T128.items()
+          if k not in ("mask_prob", "max_predictions", "mask_token_id")}
+    lm["objective"] = "causal_lm"
+    a = tf.first_batches(lm, SMOL, BIG_SEED, 2)
+    b = tf.first_batches(lm, SMOL, BIG_SEED, 2)
+    c = tf.first_batches(lm, SMOL, BIG_SEED + 1, 1)
+    for x, y in zip(a, b):
+        assert (x["tokens"] == y["tokens"]).all()
+        assert (x["labels"] == y["labels"]).all()
+    assert (a[0]["tokens"] != c[0]["tokens"]).any()
+    batch = a[0]
+    assert batch["tokens"].shape == batch["labels"].shape == (32, 128)
+    # labels[t] is tokens[t + 1]; the last position is not predicted
+    assert (batch["labels"][:, :-1] == batch["tokens"][:, 1:]).all()
+    assert (batch["labels"][:, -1] == tf.IGNORE).all()
+    assert tf.predictions_per_row(lm) == 127
+    assert batch["tokens"].min() >= lm["first_token_id"]
+    assert batch["tokens"].max() < SMOL["vocab_size"]
+    rows = {r.tobytes() for x in a for r in x["tokens"]}
+    assert len(rows) == 64
+
+
+def test_a_training_mix_names_a_known_objective():
+    assert T128["objective"] == "mlm"
+    with pytest.raises(ValueError, match="'next_sentence'"):
+        tf.first_batches(dict(T128, objective="next_sentence"), BERT, 1, 1)
+    with pytest.raises(KeyError, match="objective"):
+        tf.predictions_per_row({k: v for k, v in T128.items()
+                                if k != "objective"})
